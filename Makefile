@@ -40,13 +40,12 @@ test-serve:
 		python -m pytest -q tests/test_serve_decode.py \
 			tests/test_serve_continuous.py tests/test_serve_autotune.py
 
-# Pallas kernel suite + measured perf variants — the jax-compat subset
-# that used to fail wholesale on the CompilerParams/set_mesh renames
-# (the CI test-kernels job keeps it from regressing)
+# Pallas kernels: interpreted sweeps against kernels/ref.py, compiles for
+# a described TPU v5e, and the measured perf variants (CI test-kernels)
 test-kernels:
 	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} \
 		python -m pytest -q tests/test_kernels.py \
-			tests/test_perf_variants.py
+			tests/test_chip_compile.py tests/test_perf_variants.py
 
 # Population search: expert personae, tournament racing, island
 # migration — includes the slow cross-executor migration/conformance

@@ -48,6 +48,8 @@ def _git_sha() -> str:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--tables", default="1,2,3,4")
     ap.add_argument("--full", action="store_true",

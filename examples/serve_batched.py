@@ -27,11 +27,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 from repro.serve import BatchedServer, FixedBatchServer, generate
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="glm4-9b")
     ap.add_argument("--requests", type=int, default=8)

@@ -8,8 +8,8 @@ without its host application:
   * ``build(variant, impl)`` — construct an executable candidate from a
     point in the variant space; ``impl='jnp'`` gives the algorithmic
     restructuring as XLA-lowerable code (what Platform A wall-clocks),
-    ``impl='pallas'`` gives the Pallas TPU kernel (validated in
-    interpret mode, modeled by Platform B)
+    ``impl='pallas'`` gives the Pallas TPU kernel (compiled on a TPU,
+    interpreted elsewhere; modeled by Platform B)
   * ``input_specs(scale)``  — shapes/dtypes/generator kinds per input
   * ``variant_space``       — the tunable-parameter grid the proposers walk
   * ``flops/traffic model`` — analytic terms for the TPU platform

@@ -41,7 +41,7 @@ class OptConfig:
     improve_eps: float = 0.01    # stop when round gain < 1%
     fe_input_sets: int = 2
     fe_scale: Optional[int] = None   # None → MEP scale
-    check_pallas: bool = False       # also interpret-check the Pallas build
+    check_pallas: bool = False       # also FE-check the Pallas build
     # adaptive measurement knobs (None → engine defaults: CI-stopped
     # reps under the R cap, incumbent racing on); the campaign fills in
     # the cross-process timing lease path

@@ -11,7 +11,8 @@ legacy fixed-R entry point.
 Two platforms mirror the paper's NVIDIA/DCU pair (DESIGN.md §3):
 
 * ``CPUPlatform``       — wall-clocks the jit-compiled jnp lowering of a
-  variant on the host CPU (a *measured* feedback signal).
+  variant on the host CPU (a *measured* feedback signal), on the CPU
+  device even in a process whose default device is a TPU.
 * ``TPUModelPlatform``  — analytic TPU v5e roofline over the case's
   flops/traffic model (+ optionally the while-aware HLO walker), since no
   TPU exists in this container.  Timing = max(compute, memory) + a fixed
@@ -197,9 +198,11 @@ class CPUPlatform(Platform):
     def time_variant(self, case, variant, scale, inputs, *, r, k,
                      budget=None, incumbent_s=None):
         from repro.core.measure import measure_fn
-        fn = self._compiled(case, variant)
-        return measure_fn(fn, inputs, r=r, k=k, cfg=budget,
-                          incumbent_s=incumbent_s)
+        cpu = jax.devices("cpu")[0]
+        with jax.default_device(cpu):
+            fn = self._compiled(case, variant)
+            return measure_fn(fn, jax.device_put(inputs, cpu), r=r, k=k,
+                              cfg=budget, incumbent_s=incumbent_s)
 
 
 class TPUModelPlatform(Platform):

@@ -769,7 +769,10 @@ def _worker_cmd() -> List[str]:
 
 
 def _worker_env() -> Dict[str, str]:
-    env = dict(os.environ)
+    """Environment of a spawned worker.  Workers evaluate on the host CPU
+    (``JAX_PLATFORMS=cpu``): a chip belongs to one process, and the parent
+    may hold it."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     src = os.path.abspath(os.path.join(
         os.path.dirname(os.path.abspath(__file__)), "..", ".."))
     parts = [src] + [p for p in env.get("PYTHONPATH", "").split(os.pathsep)

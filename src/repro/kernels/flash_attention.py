@@ -5,11 +5,13 @@ TPU adaptation of the CUDA flash pattern: the (q-block × k-block) grid maps
 to pallas grid dimensions with the k loop marked 'arbitrary' so the running
 max / denominator / accumulator live in VMEM scratch across k steps; tiles
 are (block_q × head_dim) / (block_k × head_dim) with head_dim on the
-128-lane axis.  Validated in interpret mode against ref.attention_ref.
+128-lane axis.  Validated against ref.attention_ref: interpreted on the
+CPU, compiled on the chip.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 import math
 
 import jax
@@ -17,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
+from repro.kernels.backend import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -67,7 +69,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def flash_attention_bhsd(q, k, v, *, block_q: int = 128, block_k: int = 128,
-                         causal: bool = True, interpret: bool = True):
+                         causal: bool = True,
+                         interpret: Optional[bool] = None):
     """q [BH, S, hd], k/v [BH, T, hd] (GQA handled by the wrapper)."""
     BH, S, hd = q.shape
     T = k.shape[1]
@@ -94,15 +97,15 @@ def flash_attention_bhsd(q, k, v, *, block_q: int = 128, block_k: int = 128,
             pltpu.VMEM((block_q,), jnp.float32),       # running denom
             pltpu.VMEM((block_q, hd), jnp.float32),    # output accumulator
         ],
-        compiler_params=_compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, softcap: float = 0.0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """Model-site signature: q [B,S,H,hd], k/v [B,T,KV,hd] (GQA)."""
     del softcap  # the pallas path does not implement softcap (glm4 uses 0)
     B, S, H, hd = q.shape

@@ -10,13 +10,14 @@ factorization, DESIGN.md / models/ssm.py).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
+from repro.kernels.backend import resolve_interpret
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref, *,
@@ -27,29 +28,37 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref, *,
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    r = r_ref[0].astype(jnp.float32)       # [chunk, K]
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    w = jnp.exp(lw_ref[0].astype(jnp.float32))
     u = u_ref[0].astype(jnp.float32)       # [1, K] bonus row
+    K = u.shape[1]
+    # k_t and w_t act down the state's rows: turn each [1, K] row into a
+    # [K, 1] column by a masked lane reduction (no transpose)
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (K, K), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (K, K), 1))
 
-    def step(t, carry):
-        s, o = carry
-        r_t, k_t, v_t, w_t = r[t], k[t], v[t], w[t]
-        kv_t = k_t[:, None] * v_t[None, :]              # [K, V]
-        o_t = r_t @ (s + u[0][:, None] * kv_t)          # [V]
-        s = w_t[:, None] * s + kv_t
-        o = o.at[t].set(o_t)
-        return s, o
+    def col(row):
+        return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
 
-    s0 = s_ref[...]
-    o0 = jnp.zeros((chunk, v.shape[1]), jnp.float32)
-    s_fin, o = jax.lax.fori_loop(0, chunk, step, (s0, o0))
-    s_ref[...] = s_fin
-    o_ref[0] = o.astype(o_ref.dtype)
+    def step(t, s):
+        # token t is read from and written to the refs: slicing a loaded
+        # value at a traced index does not lower on the TPU
+        row = pl.ds(t, 1)
+        r_t = r_ref[0, row, :].astype(jnp.float32)            # [1, K]
+        k_t = k_ref[0, row, :].astype(jnp.float32)            # [1, K]
+        v_t = v_ref[0, row, :].astype(jnp.float32)            # [1, V]
+        w_t = jnp.exp(lw_ref[0, row, :].astype(jnp.float32))  # [1, K]
+        kv_t = col(k_t) * v_t                                 # [K, V]
+        # o_t = r_t @ (s + diag(u) kv_t)
+        bonus = jnp.sum(r_t * u * k_t, axis=1, keepdims=True)  # [1, 1]
+        o_t = jnp.dot(r_t, s, preferred_element_type=jnp.float32) \
+            + bonus * v_t
+        o_ref[0, row, :] = o_t.astype(o_ref.dtype)
+        return col(w_t) * s + kv_t
+
+    s_ref[...] = jax.lax.fori_loop(0, chunk, step, s_ref[...])
 
 
-def wkv_pallas(r, k, v, lw, u, *, chunk: int = 64, interpret: bool = True):
+def wkv_pallas(r, k, v, lw, u, *, chunk: int = 64,
+               interpret: Optional[bool] = None):
     """r/k/v/lw [B,S,H,K]; u [H,K] → (o [B,S,H,V], final state [B,H,K,V])."""
     B, S, H, K = r.shape
     V = v.shape[-1]
@@ -58,7 +67,9 @@ def wkv_pallas(r, k, v, lw, u, *, chunk: int = 64, interpret: bool = True):
         chunk -= 1
     NC = S // chunk
     fold = lambda t: t.transpose(0, 2, 1, 3).reshape(B * H, S, K)
-    rf, kf, vf, lwf = fold(r), fold(k), fold(v), fold(lw)
+    # f32 in VMEM: one token is one sublane row, which a dynamic index
+    # can address (packed bf16 rows cannot be sliced one at a time)
+    rf, kf, vf, lwf = (fold(t).astype(jnp.float32) for t in (r, k, v, lw))
     uf = jnp.broadcast_to(u[None], (B, H, K)).reshape(B * H, 1, K)
 
     kernel = functools.partial(_wkv_kernel, chunk=chunk)
@@ -73,13 +84,13 @@ def wkv_pallas(r, k, v, lw, u, *, chunk: int = 64, interpret: bool = True):
             pl.BlockSpec((1, 1, K), lambda b, c: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, V), lambda b, c: (b, c, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, S, V), r.dtype),
+        out_shape=jax.ShapeDtypeStruct((B * H, S, V), jnp.float32),
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        compiler_params=_compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rf, kf, vf, lwf, uf)
-    o = o.reshape(B, H, S, V).transpose(0, 2, 1, 3)
+    o = o.reshape(B, H, S, V).transpose(0, 2, 1, 3).astype(r.dtype)
     # final state is recomputed cheaply outside the kernel when needed by
     # serving (decode keeps its own state); training only needs o.
     return o

@@ -7,51 +7,62 @@ MXU-friendly restructuring of the CUDA selective-scan (DESIGN.md §3).
 """
 from __future__ import annotations
 
-import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
+from repro.kernels.backend import resolve_interpret
 
 
-def _ssd_kernel(u_ref, la_ref, b_ref, c_ref, y_ref, s_ref, *, chunk: int):
+def _ssd_kernel(u_ref, la_ref, b_ref, c_ref, y_ref, s_ref):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    u = u_ref[0].astype(jnp.float32)          # [c, Hb, P]  (dt·x)
-    la_step = la_ref[0].astype(jnp.float32)   # [c, Hb]     (log decay ≤ 0)
-    Bm = b_ref[0].astype(jnp.float32)         # [c, N]
-    Cm = c_ref[0].astype(jnp.float32)         # [c, N]
-    c, Hb, P = u.shape
-    N = Bm.shape[-1]
-
-    la = jnp.cumsum(la_step, axis=0)                         # [c, Hb]
-    dmat = la[:, None, :] - la[None, :, :]                   # [t, s, Hb]
-    mask = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    dmat = jnp.where(mask[..., None], jnp.exp(dmat), 0.0)
+    f32 = jnp.float32
+    Bm = b_ref[0].astype(f32)                 # [c, N]
+    Cm = c_ref[0].astype(f32)                 # [c, N]
+    Hb, c = u_ref.shape[1], u_ref.shape[2]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    causal = rows >= cols
+    eye = rows == cols
     cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # [t, s]
-    scores = cb[..., None] * dmat                            # [t, s, Hb]
-    y_intra = jnp.einsum("tsh,shp->thp", scores, u)
+                             preferred_element_type=f32)     # [t, s]
 
-    s_prev = s_ref[...]                                      # [Hb, P, N]
-    y_cross = jnp.einsum("th,tn,hpn->thp", jnp.exp(la), Cm, s_prev)
+    def head(h, carry):
+        # every product is 2-D: the TPU lowering has no batched matmul
+        u = u_ref[0, h].astype(f32)           # [c, P]  (dt·x)
+        # in-chunk prefix sum of the log decays (≤ 0) as a product with
+        # a lower-triangular matrix of ones: the TPU lowering has no cumsum
+        la = jnp.dot(causal.astype(f32), la_ref[0, h].astype(f32),
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=f32)             # [c, 1]
+        la_row = jnp.sum(jnp.where(eye, la, 0.0), axis=0,
+                         keepdims=True)                      # [1, c]
+        dmat = jnp.where(causal, jnp.exp(la - la_row), 0.0)  # [t, s]
+        y_intra = jnp.dot(cb * dmat, u, preferred_element_type=f32)
+        s_prev = s_ref[h]                                    # [P, N]
+        y_cross = jnp.exp(la) * jax.lax.dot_general(
+            Cm, s_prev, (((1,), (1,)), ((), ())),
+            preferred_element_type=f32)                      # [c, P]
+        la_end = la[c - 1:c, :]                              # [1, 1]
+        du = jnp.exp(la_end - la) * u                        # [s, P]
+        upd = jnp.dot(du.T, Bm, preferred_element_type=f32)  # [P, N]
+        s_ref[h] = jnp.exp(jnp.sum(la_end)) * s_prev + upd    # scalar decay
+        y_ref[0, h] = (y_intra + y_cross).astype(y_ref.dtype)
+        return carry
 
-    dend = jnp.exp(la[-1:, :] - la)                          # [c, Hb]
-    upd = jnp.einsum("sh,shp,sn->hpn", dend, u, Bm)
-    s_ref[...] = jnp.exp(la[-1])[:, None, None] * s_prev + upd
-    y_ref[0] = (y_intra + y_cross).astype(y_ref.dtype)
+    jax.lax.fori_loop(0, Hb, head, 0)
 
 
 def ssd_pallas(xh, dt, a_log, B_t, C_t, *, chunk: int = 128,
-               block_h: int = 0, interpret: bool = True):
+               block_h: int = 8, interpret: Optional[bool] = None):
     """xh [B,S,H,P]; dt [B,S,H]; a_log [H]; B_t/C_t [B,S,N] → y [B,S,H,P].
     Matches ref.ssd_ref (output only; serving keeps its own state)."""
     Bb, S, H, P = xh.shape
@@ -60,32 +71,36 @@ def ssd_pallas(xh, dt, a_log, B_t, C_t, *, chunk: int = 128,
     while S % chunk:
         chunk -= 1
     NC = S // chunk
-    block_h = block_h or H
+    # heads per grid step; the kernel walks them one at a time, and a
+    # block of all heads outgrows VMEM at hymba's 50
+    block_h = min(block_h, H)
     while H % block_h:
         block_h -= 1
     nH = H // block_h
 
     f32 = jnp.float32
-    u = (dt.astype(f32)[..., None] * xh.astype(f32))         # [B,S,H,P]
+    # heads lead, so a head's chunk is one [chunk, P] tile
+    u = (dt.astype(f32)[..., None] * xh.astype(f32)).transpose(0, 2, 1, 3)
     la_step = -jnp.exp(a_log.astype(f32))[None, None] * dt.astype(f32)
+    la_step = la_step.transpose(0, 2, 1)[..., None]          # [B,H,S,1]
 
-    kernel = functools.partial(_ssd_kernel, chunk=chunk)
     y = pl.pallas_call(
-        kernel,
+        _ssd_kernel,
         grid=(Bb, nH, NC),
         in_specs=[
-            pl.BlockSpec((1, chunk, block_h, P),
-                         lambda b, h, ci: (b, ci, h, 0)),
-            pl.BlockSpec((1, chunk, block_h), lambda b, h, ci: (b, ci, h)),
+            pl.BlockSpec((1, block_h, chunk, P),
+                         lambda b, h, ci: (b, h, ci, 0)),
+            pl.BlockSpec((1, block_h, chunk, 1),
+                         lambda b, h, ci: (b, h, ci, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, h, ci: (b, ci, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, h, ci: (b, ci, 0)),
         ],
-        out_specs=pl.BlockSpec((1, chunk, block_h, P),
-                               lambda b, h, ci: (b, ci, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((Bb, S, H, P), xh.dtype),
+        out_specs=pl.BlockSpec((1, block_h, chunk, P),
+                               lambda b, h, ci: (b, h, ci, 0)),
+        out_shape=jax.ShapeDtypeStruct((Bb, H, S, P), xh.dtype),
         scratch_shapes=[pltpu.VMEM((block_h, P, N), jnp.float32)],
-        compiler_params=_compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(u, la_step, B_t, C_t)
-    return y
+    return y.transpose(0, 2, 1, 3)

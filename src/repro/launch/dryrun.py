@@ -11,10 +11,9 @@ from typing import Dict, Optional  # noqa: E402
 import jax               # noqa: E402
 
 from repro.configs import REGISTRY, SHAPES, cell_applicable, get_config, get_shape  # noqa: E402
-from repro.launch import hlo_cost        # noqa: E402
 from repro.launch import roofline as rl  # noqa: E402
 from repro.launch import mesh as hw      # noqa: E402
-from repro.launch.mesh import make_ctx, make_production_mesh, use_mesh  # noqa: E402
+from repro.launch.mesh import make_ctx, make_production_mesh  # noqa: E402
 from repro.launch.specs import input_specs  # noqa: E402
 from repro.models import get_model       # noqa: E402
 from repro.sharding.ctx import DEFAULT_RULES  # noqa: E402
@@ -122,7 +121,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
 
     jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                      donate_argnums=donate)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()
@@ -161,7 +160,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
               f"dominant={roof.dominant} step={roof.step_s*1e3:.2f} ms "
               f"mfu_bound={roof.model_flops_utilization:.3f}")
         print("  memory_analysis:", mem)
-        ca = hlo_cost.xla_cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         print("  cost_analysis: flops=%.3e bytes=%.3e" %
               (ca.get("flops", 0.0), ca.get("bytes accessed", 0.0)))
         print("  collectives:", roof.collectives.bytes_by_kind)

@@ -364,13 +364,3 @@ class HloModule:
 
 def analyze(hlo_text: str, f32_bytes: int = 4) -> Cost:
     return HloModule(hlo_text, f32_bytes=f32_bytes).entry_cost()
-
-
-def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalized across jax versions: 0.4.x
-    returns one properties dict per program (a list), newer jax the dict
-    itself."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca

@@ -11,25 +11,15 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-except ImportError:          # older jax: meshes are implicitly Auto-typed
-    AxisType = None
+from jax.sharding import AxisType
 
 from repro.sharding.ctx import ShardCtx
-
-
-def _axis_types_kw(n: int) -> dict:
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_ctx(mesh, preset: str = "default", **kw) -> ShardCtx:
@@ -60,35 +50,13 @@ def make_ctx(mesh, preset: str = "default", **kw) -> ShardCtx:
                     rules=rules, **kw)
 
 
-try:                                  # modern spelling (jax >= 0.5)
-    shard_map = jax.shard_map
-except AttributeError:                # jax 0.4.x: experimental home, and
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-    # check_vma was spelled check_rep there
-
-    def shard_map(f, *, check_vma=True, **kw):
-        return _shard_map_04(f, check_rep=check_vma, **kw)
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh`` for sharded computation.
-
-    ``jax.set_mesh`` is the modern spelling; jax 0.4.x doesn't have it —
-    there the ``Mesh`` object is its own context manager.  Every caller
-    (dryrun, the distributed tests) routes through this one shim instead
-    of repeating the ``hasattr`` fallback."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def make_smoke_mesh(n: int = 0):
     """Mesh over whatever local devices exist (tests use subprocesses with
     --xla_force_host_platform_device_count to get >1)."""
     n = n or len(jax.devices())
     model = 2 if n % 2 == 0 and n > 1 else 1
     return jax.make_mesh((n // model, model), ("data", "model"),
-                         **_axis_types_kw(2))
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 # TPU v5e hardware model (roofline constants)

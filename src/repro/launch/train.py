@@ -20,6 +20,7 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.data import SyntheticLMData, make_global_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 from repro.runtime import (FailureInjector, FaultTolerantLoop,
                            StragglerWatchdog, make_compression_hook)
@@ -46,6 +47,7 @@ def build(arch: str, *, smoke: bool, batch: int, seq: int, lr: float,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="stablelm-3b")
     ap.add_argument("--smoke", action="store_true",

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig
-from repro.launch.mesh import shard_map
 from repro.sharding.ctx import ShardCtx
 
 NEG_INF = -1e30
@@ -232,7 +231,7 @@ def attention_context_parallel(q, k, v, *, ctx: ShardCtx, q_chunk: int = 256,
                                  softcap=softcap, q_offset=off)
 
     spec = P(ctx.dp, tp, None, None)
-    return shard_map(local, mesh=ctx.mesh, in_specs=(spec, spec, spec),
+    return jax.shard_map(local, mesh=ctx.mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)
 
 
@@ -356,13 +355,13 @@ def flash_decode_sharded(q, k_cache, v_cache, ctx: ShardCtx,
     len_spec = P(bspec) if bspec else P()
     if k_scale is None:
         fn = lambda qh, kl, vl, lens: local(qh, kl, vl, lens, None, None)
-        out = shard_map(
+        out = jax.shard_map(
             fn, mesh=ctx.mesh,
             in_specs=(q_spec, kv_spec, kv_spec, len_spec),
             out_specs=q_spec, check_vma=False,
         )(qh, k_cache, v_cache, length)
     else:
-        out = shard_map(
+        out = jax.shard_map(
             local, mesh=ctx.mesh,
             in_specs=(q_spec, kv_spec, kv_spec, len_spec, kv_spec, kv_spec),
             out_specs=q_spec, check_vma=False,
@@ -499,7 +498,7 @@ def moe_block(x, p, cfg: ModelConfig, ctx: ShardCtx):
 
             dp = ctx.dp
             wspec = P(None, None, tp)
-            out = shard_map(
+            out = jax.shard_map(
                 local, mesh=ctx.mesh,
                 in_specs=(P(dp, None, None, None), wspec, wspec,
                           P(None, tp, None), P(dp, None), P(dp, None),

@@ -170,16 +170,13 @@ class BatchedServer:
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.cache)
 
     def _aot(self, jitted, *avals):
-        """AOT-compile ``jitted`` for ``avals`` (falls back to the plain
-        jit object — which compiles on first call — if lowering fails).
-        With ``aot=False`` the jit object is returned as-is and compiles
-        lazily on first call."""
+        """AOT-compile ``jitted`` for ``avals``; a compile error propagates
+        (a kernel the chip's compiler refuses must not be served by some
+        other path).  With ``aot=False`` the jit object is returned as-is
+        and compiles lazily on first call."""
         if not self.aot:
             return jitted
-        try:
-            ex = jitted.lower(self.params, *avals).compile()
-        except Exception:               # noqa: BLE001 — serving must start
-            ex = jitted
+        ex = jitted.lower(self.params, *avals).compile()
         self.aot_compiles += 1
         return ex
 

@@ -1,11 +1,8 @@
-"""Diagnosis classifier, jax-compat shims, learned pattern ranking, and
-LLM-reply validation (PR: diagnosis-driven proposals)."""
+"""Diagnosis classifier, learned pattern ranking, and LLM-reply
+validation (PR: diagnosis-driven proposals)."""
 import json
 import os
 
-import jax
-import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from repro.core.diagnosis import (BALANCED_MARGIN, BOTTLENECKS,
@@ -17,75 +14,6 @@ from repro.core.profiler import TPUModelPlatform
 from repro.core.proposer import (HeuristicProposer, LLMProposer,
                                  ProposalError, RoundState, _json_span,
                                  _validated)
-
-
-# ---------------------------------------------------------------------------
-# jax version-compat shims (both API spellings, monkeypatched)
-# ---------------------------------------------------------------------------
-class _FakeParams:
-    def __init__(self, **kw):
-        self.kw = kw
-
-
-class TestCompilerParamsShim:
-    def test_new_spelling_only(self, monkeypatch):
-        from jax.experimental.pallas import tpu as pltpu
-        from repro.kernels import _compat
-        monkeypatch.setattr(pltpu, "CompilerParams", _FakeParams,
-                            raising=False)
-        monkeypatch.delattr(pltpu, "TPUCompilerParams", raising=False)
-        p = _compat.compiler_params(dimension_semantics=("parallel",))
-        assert isinstance(p, _FakeParams)
-        assert p.kw == {"dimension_semantics": ("parallel",)}
-
-    def test_old_spelling_only(self, monkeypatch):
-        from jax.experimental.pallas import tpu as pltpu
-        from repro.kernels import _compat
-        monkeypatch.delattr(pltpu, "CompilerParams", raising=False)
-        monkeypatch.setattr(pltpu, "TPUCompilerParams", _FakeParams,
-                            raising=False)
-        p = _compat.compiler_params(dimension_semantics=("arbitrary",))
-        assert isinstance(p, _FakeParams)
-
-    def test_neither_spelling_raises(self, monkeypatch):
-        from jax.experimental.pallas import tpu as pltpu
-        from repro.kernels import _compat
-        monkeypatch.delattr(pltpu, "CompilerParams", raising=False)
-        monkeypatch.delattr(pltpu, "TPUCompilerParams", raising=False)
-        with pytest.raises(AttributeError):
-            _compat.compiler_params()
-
-
-class TestUseMeshShim:
-    def test_modern_set_mesh_path(self, monkeypatch):
-        from repro.launch import mesh as lm
-        sentinel = object()
-        calls = []
-        monkeypatch.setattr(jax, "set_mesh",
-                            lambda m: (calls.append(m), sentinel)[1],
-                            raising=False)
-        m = object()
-        assert lm.use_mesh(m) is sentinel
-        assert calls == [m]
-
-    def test_legacy_mesh_as_context_manager(self, monkeypatch):
-        from repro.launch import mesh as lm
-        monkeypatch.delattr(jax, "set_mesh", raising=False)
-        m = lm.make_smoke_mesh()
-        assert lm.use_mesh(m) is m       # Mesh is its own ctx manager
-        with lm.use_mesh(m):
-            pass
-
-
-class TestShardMapShim:
-    def test_check_vma_kw_accepted(self):
-        from jax.sharding import PartitionSpec as P
-        from repro.launch.mesh import shard_map
-        mesh = jax.make_mesh((1,), ("x",))
-        f = shard_map(lambda a: a * 2.0, mesh=mesh, in_specs=P(),
-                      out_specs=P(), check_vma=False)
-        x = jnp.ones((4,), jnp.float32)
-        np.testing.assert_allclose(np.asarray(jax.jit(f)(x)), 2.0)
 
 
 # ---------------------------------------------------------------------------

@@ -33,7 +33,7 @@ def test_sharded_train_step_matches_single_device():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
         from repro.models import get_model
-        from repro.launch.mesh import make_smoke_mesh, use_mesh
+        from repro.launch.mesh import make_smoke_mesh
         from repro.sharding.ctx import ShardCtx
         from repro.train import AdamWConfig, init_state
         from repro.train.steps import make_train_step
@@ -57,7 +57,7 @@ def test_sharded_train_step_matches_single_device():
         p_sh = ctx.tree_shardings(axes, params)
         params_sh = jax.tree.map(jax.device_put, params, p_sh)
         opt = init_state(params_sh)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             s1 = jax.jit(make_train_step(m1, AdamWConfig(lr=1e-3)))
             batch = make_global_batch(
                 data, 0, NamedSharding(mesh, P("data", None)))
@@ -74,7 +74,7 @@ def test_sharded_train_step_matches_single_device():
 def test_flash_decode_sharded_matches_local():
     run_subprocess("""
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.launch.mesh import make_smoke_mesh, use_mesh
+        from repro.launch.mesh import make_smoke_mesh
         from repro.sharding.ctx import ShardCtx
         from repro.models.layers import attention_decode, flash_decode_sharded
 
@@ -87,7 +87,7 @@ def test_flash_decode_sharded_matches_local():
         v = jnp.asarray(rng.standard_normal((B, T, KV, hd)), jnp.float32)
         lens = jnp.full((B,), T, jnp.int32)
         want = attention_decode(q, k, v, lens)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             k_sh = jax.device_put(k, NamedSharding(mesh, P(None, "data")))
             v_sh = jax.device_put(v, NamedSharding(mesh, P(None, "data")))
             got = jax.jit(lambda q, k, v, l:
@@ -102,7 +102,7 @@ def test_flash_decode_sharded_matches_local():
 def test_compressed_psum_shard_map():
     run_subprocess("""
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.launch.mesh import make_smoke_mesh, shard_map, use_mesh
+        from repro.launch.mesh import make_smoke_mesh
         from repro.runtime.compress import compressed_psum
 
         mesh = make_smoke_mesh()
@@ -114,8 +114,8 @@ def test_compressed_psum_shard_map():
             out, res = compressed_psum(xl, "data")
             return out
 
-        with use_mesh(mesh):
-            got = jax.jit(shard_map(
+        with jax.set_mesh(mesh):
+            got = jax.jit(jax.shard_map(
                 f, mesh=mesh, in_specs=P("data", None),
                 out_specs=P("data", None)))(x)
         want = jnp.tile(jnp.sum(x.reshape(n_data, 4, 32), axis=0),
@@ -132,7 +132,7 @@ def test_gather_fsdp_produces_allgather_not_allreduce():
     run_subprocess("""
         import re
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.launch.mesh import make_smoke_mesh, use_mesh
+        from repro.launch.mesh import make_smoke_mesh
         from repro.sharding.ctx import ShardCtx
 
         mesh = make_smoke_mesh()
@@ -144,7 +144,7 @@ def test_gather_fsdp_produces_allgather_not_allreduce():
 
         w = jax.ShapeDtypeStruct((256, 512), jnp.float32)
         x = jax.ShapeDtypeStruct((64, 256), jnp.float32)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             c = jax.jit(jax.grad(step), in_shardings=(
                 NamedSharding(mesh, P("data", "model")),
                 NamedSharding(mesh, P("data", None)))).lower(w, x).compile()
@@ -161,7 +161,7 @@ def test_moe_dispatch_sharded_matches_single_device():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
         from repro.models import get_model
-        from repro.launch.mesh import make_smoke_mesh, use_mesh
+        from repro.launch.mesh import make_smoke_mesh
         from repro.sharding.ctx import ShardCtx
 
         cfg = dataclasses.replace(get_config("qwen2-moe-a2.7b").reduced(),
@@ -174,7 +174,7 @@ def test_moe_dispatch_sharded_matches_single_device():
         toks = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0,
                                   cfg.vocab_size)
         want, _, _ = jax.jit(m0.forward)(params, toks)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             got, _, _ = jax.jit(m1.forward)(
                 jax.tree.map(jax.device_put, params,
                              ctx.tree_shardings(m1.param_axes(), params)),

@@ -20,7 +20,7 @@ def test_matches_cost_analysis_scan_free():
     a = jax.ShapeDtypeStruct((256, 256), jnp.float32)
     compiled = _compiled_text(fn, a, a)
     got = hlo_cost.analyze(compiled.as_text())
-    want = hlo_cost.xla_cost_analysis(compiled)["flops"]
+    want = compiled.cost_analysis()["flops"]
     # dot flops dominate; elementwise accounting differs slightly
     assert abs(got.flops - want) / want < 0.05
 
@@ -38,7 +38,7 @@ def test_while_trip_count_multiplies():
     got = hlo_cost.analyze(compiled.as_text())
     per_iter = 2 * 64 * 128 * 128
     assert got.flops >= 13 * per_iter                    # walker multiplies
-    assert hlo_cost.xla_cost_analysis(compiled)["flops"] \
+    assert compiled.cost_analysis()["flops"] \
         < 3 * per_iter                                   # XLA does not
 
 

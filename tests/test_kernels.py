@@ -182,3 +182,34 @@ def test_kernel_registry_integration():
         swapped, _, _ = model.forward(params, toks)
     np.testing.assert_allclose(np.asarray(base), np.asarray(swapped),
                                rtol=5e-3, atol=5e-3)
+
+
+# ---------------------------------------------------------------------------
+def test_interpret_mode_is_the_backends_choice(monkeypatch):
+    from repro.kernels import backend
+    assert backend.resolve_interpret() is True          # CPU: interpreted
+    assert backend.resolve_interpret(False) is False    # compile for a chip
+    monkeypatch.setattr(backend.jax, "default_backend", lambda: "tpu")
+    assert backend.resolve_interpret() is False         # TPU: compiled
+    with pytest.raises(ValueError):
+        backend.resolve_interpret(True)                 # never interpreted
+
+
+@pytest.mark.parametrize("block,dim,align,want", [
+    (128, 64, 128, (64, 64)),        # block covers the axis: whole axis
+    (32, 64, 8, (32, 64)),           # aligned divisor
+    (32, 48, 128, (48, 48)),         # rounds up to 128 > 48: whole axis
+    (512, 1408, 128, (128, 1408)),   # largest aligned divisor of 11 x 128
+    (128, 1000, 128, (128, 1024)),   # none divides: pad
+    (100, 1000, 8, (40, 1000)),      # 96..48 do not divide 1000; 40 does
+])
+def test_fit_block(block, dim, align, want):
+    from repro.kernels.backend import fit_block
+    assert fit_block(block, dim, align) == want
+
+
+def test_matmul_pallas_pads_an_axis_no_block_divides():
+    a, b = randn((200, 136)), randn((136, 260))
+    got = matmul_pallas(a, b, block_m=64, block_n=128, block_k=128)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(a @ b),
+                               rtol=1e-4, atol=1e-4)

@@ -23,7 +23,7 @@ def run_subprocess(body: str, devices: int = 8) -> str:
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
         from repro.models import get_model
-        from repro.launch.mesh import make_smoke_mesh, make_ctx, use_mesh
+        from repro.launch.mesh import make_smoke_mesh, make_ctx
     """) + textwrap.dedent(body)
     env = dict(os.environ, PYTHONPATH=SRC)
     env.pop("XLA_FLAGS", None)
@@ -47,7 +47,7 @@ def test_context_parallel_forward_matches(arch):
         want, _, _ = jax.jit(m0.forward)(params, toks)
         ctx = make_ctx(mesh, preset="cp")
         m1 = get_model(cfg, ctx)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             p_sh = jax.tree.map(jax.device_put, params,
                                 ctx.tree_shardings(m1.param_axes(), params))
             got, _, _ = jax.jit(m1.forward)(
@@ -71,7 +71,7 @@ def test_moe_shard_map_combine_matches_einsum():
         ctx = make_ctx(mesh, preset="default", moe_impl="shard_map",
                        seq_shard=False)
         m1 = get_model(cfg, ctx)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             p_sh = jax.tree.map(jax.device_put, params,
                                 ctx.tree_shardings(m1.param_axes(), params))
             got, _, _ = jax.jit(m1.forward)(
@@ -103,7 +103,7 @@ def test_tp_seq_decode_matches_local():
             rules=dict(DEFAULT_RULES, kv_seq="__tp__", kv_heads=None),
             decode_kv="tp_seq")
         m1 = get_model(cfg, ctx)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             p_sh = jax.tree.map(jax.device_put, params,
                                 ctx.tree_shardings(m1.param_axes(), params))
             cache_sh = ctx.tree_shardings(m1.cache_axes(),

@@ -116,6 +116,33 @@ def test_aot_off_still_serves_identically():
     check_equivalence(srv, pairs)
 
 
+def test_compile_error_propagates_instead_of_a_lazy_jit(monkeypatch):
+    """A kernel the compiler refuses must stop the server from starting,
+    not be served by a jit that compiles (or fails) on first call."""
+    from repro.serve import BatchedServer
+    from serving_stub import StubModel
+    model = StubModel()
+    params = model.init_params(jax.random.PRNGKey(0))
+    real_jit = jax.jit
+
+    class Refused(RuntimeError):
+        pass
+
+    class Unlowerable:
+        def __init__(self, fn):
+            self.jitted = real_jit(fn)
+
+        def __call__(self, *args):
+            return self.jitted(*args)
+
+        def lower(self, *args):
+            raise Refused("the compiler refused this program")
+
+    monkeypatch.setattr(jax, "jit", Unlowerable)
+    with pytest.raises(Refused):
+        BatchedServer(model, params, slots=2, max_len=64)
+
+
 def test_bucket_telemetry_reaches_autotuner():
     tel = ops.Telemetry()
     srv = make_server(slots=2, max_len=64, telemetry=tel)

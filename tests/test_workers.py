@@ -42,6 +42,16 @@ def _job(case="gemm", seed=0, label=""):
 
 
 # ------------------------------------------------------------- wire form --
+def test_spawned_workers_run_on_the_cpu(monkeypatch):
+    """A chip belongs to one process: workers never reach for it, even
+    when the parent that spawns them runs on the TPU."""
+    from repro.core.workers import _worker_env
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    env = _worker_env()
+    assert env["JAX_PLATFORMS"] == "cpu"
+    assert env["PYTHONPATH"].split(os.pathsep)[0].endswith("src")
+
+
 def test_platform_registry_roundtrip():
     assert platform_from_name("tpu-v5e-model").name == "tpu-v5e-model"
     assert platform_from_name("cpu").name == "cpu"
