@@ -29,6 +29,11 @@ greedy decode continuously:
   the request's bucket, so each (site, bucket) pair is a distinct
   telemetry site and ``serve.autotune`` campaigns per traffic bucket at
   that bucket's observed scale.
+* **Profiler spans** — ``submit`` and every phase of ``step`` open a
+  ``jax.profiler.TraceAnnotation`` named ``serve.*``, so a profiler
+  trace shows on the device's clock what the host did in each idle gap.
+  They record only while a profiler runs and cost under a microsecond
+  each otherwise.
 
 ``FixedBatchServer`` preserves the pre-continuous baseline (single shared
 decode position, one prefill call per request, prompts padded to one
@@ -43,6 +48,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.kernels import ops
 
@@ -239,7 +245,8 @@ class BatchedServer:
         if epoch != self._epoch:
             self._epoch = epoch
             self.swap_epochs += 1
-            self._trace_steps()
+            with TraceAnnotation("serve.rebuild"):
+                self._trace_steps()
 
     # --------------------------------------------------------- admission --
     def bucket_of(self, prompt_len: int) -> int:
@@ -253,9 +260,10 @@ class BatchedServer:
                          f"bucket {self.buckets[-1]} (max_len={self.max_len})")
 
     def submit(self, prompt: np.ndarray, max_new: int = 16) -> Request:
-        req = Request(rid=next(self._rid), prompt=prompt, max_new=max_new,
-                      bucket=self.bucket_of(len(prompt)))
-        self.queue.append(req)
+        with TraceAnnotation("serve.submit"):
+            req = Request(rid=next(self._rid), prompt=prompt,
+                          max_new=max_new, bucket=self.bucket_of(len(prompt)))
+            self.queue.append(req)
         return req
 
     def _finish(self, req: Request, slot: Optional[int]) -> None:
@@ -283,23 +291,26 @@ class BatchedServer:
             finished_at_prefill = False
             for bucket, reqs in groups.items():
                 n_pad = _next_pow2(len(reqs))  # bounded executable count
-                toks = np.zeros((n_pad, bucket), np.int32)
-                lens = np.ones((n_pad,), np.int32)
-                # tentative slot per row; pad rows point past the pool
-                # and are dropped by the in-executable splice.  A row
-                # whose request finishes at its prefill token simply
-                # leaves garbage in a slot that stays free — dead slots
-                # are masked at decode and overwritten on re-admission.
-                si = np.full((n_pad,), self.slots, np.int32)
-                for r, req in enumerate(reqs):
-                    toks[r, :len(req.prompt)] = req.prompt
-                    lens[r] = len(req.prompt)
-                    si[r] = free[fi]
-                    fi += 1
-                first, self.cache = self._get_prefill(bucket, n_pad)(
-                    self.params, jnp.asarray(toks), jnp.asarray(lens),
-                    self.cache, jnp.asarray(si))
-                first = np.asarray(first)
+                with TraceAnnotation("serve.prefill"):
+                    toks = np.zeros((n_pad, bucket), np.int32)
+                    lens = np.ones((n_pad,), np.int32)
+                    # tentative slot per row; pad rows point past the
+                    # pool and are dropped by the in-executable splice.
+                    # A row whose request finishes at its prefill token
+                    # simply leaves garbage in a slot that stays free —
+                    # dead slots are masked at decode and overwritten on
+                    # re-admission.
+                    si = np.full((n_pad,), self.slots, np.int32)
+                    for r, req in enumerate(reqs):
+                        toks[r, :len(req.prompt)] = req.prompt
+                        lens[r] = len(req.prompt)
+                        si[r] = free[fi]
+                        fi += 1
+                    first, self.cache = self._get_prefill(bucket, n_pad)(
+                        self.params, jnp.asarray(toks), jnp.asarray(lens),
+                        self.cache, jnp.asarray(si))
+                with TraceAnnotation("serve.prefill_wait"):
+                    first = np.asarray(first)
                 for r, req in enumerate(reqs):
                     tok = int(first[r])
                     req.tokens.append(tok)
@@ -326,34 +337,41 @@ class BatchedServer:
         ragged decode over every occupied slot.  Returns the amount of
         work done — requests admitted plus tokens decoded — so ``0``
         means the server is idle (queue empty, no live slots)."""
-        self._refresh_impls()
-        worked = self._admit()
-        live = [s for s in range(self.slots) if self.active[s] is not None]
-        if not live:
-            return worked
-        toks = np.zeros((self.slots, 1), np.int32)
-        for s in live:
-            toks[s, 0] = self.active[s].tokens[-1]
-        # per-slot positions: dead slots decode a dummy token at pos 0
-        # (their row is fully overwritten at the next admission)
-        nxt, self.cache = self._get_decode()(
-            self.params, self.cache, jnp.asarray(toks),
-            jnp.asarray(self.pos))
-        nxt = np.asarray(nxt)
-        for s in live:
-            req = self.active[s]
-            tok = int(nxt[s])
-            req.tokens.append(tok)
-            self.pos[s] += 1
-            # context length this token was decoded at (traffic weighting)
-            self.telemetry.observe(self.site, scale=int(self.pos[s]),
-                                   tokens=1, kind="decode",
-                                   bucket=req.bucket)
-            if ((self.eos_id is not None and tok == self.eos_id)
-                    or len(req.tokens) >= req.max_new
-                    or int(self.pos[s]) >= self.max_len):
-                self._finish(req, s)          # EOS / budget / cache full
-        return worked + len(live)
+        with TraceAnnotation("serve.step"):
+            self._refresh_impls()
+            worked = self._admit()
+            live = [s for s in range(self.slots)
+                    if self.active[s] is not None]
+            if not live:
+                return worked
+            with TraceAnnotation("serve.decode"):
+                toks = np.zeros((self.slots, 1), np.int32)
+                for s in live:
+                    toks[s, 0] = self.active[s].tokens[-1]
+                # per-slot positions: dead slots decode a dummy token at
+                # pos 0 (their row is fully overwritten at the next
+                # admission)
+                nxt, self.cache = self._get_decode()(
+                    self.params, self.cache, jnp.asarray(toks),
+                    jnp.asarray(self.pos))
+            with TraceAnnotation("serve.decode_wait"):
+                nxt = np.asarray(nxt)
+            with TraceAnnotation("serve.bookkeeping"):
+                for s in live:
+                    req = self.active[s]
+                    tok = int(nxt[s])
+                    req.tokens.append(tok)
+                    self.pos[s] += 1
+                    # context length this token was decoded at (traffic
+                    # weighting)
+                    self.telemetry.observe(self.site, scale=int(self.pos[s]),
+                                           tokens=1, kind="decode",
+                                           bucket=req.bucket)
+                    if ((self.eos_id is not None and tok == self.eos_id)
+                            or len(req.tokens) >= req.max_new
+                            or int(self.pos[s]) >= self.max_len):
+                        self._finish(req, s)  # EOS / budget / cache full
+            return worked + len(live)
 
     def run(self, max_steps: int = 1000) -> List[Request]:
         """Drive steps until the queue *and* the slots are both drained
