@@ -235,29 +235,41 @@ def attention_context_parallel(q, k, v, *, ctx: ShardCtx, q_chunk: int = 256,
                          out_specs=spec, check_vma=False)(q, k, v)
 
 
-# ---- decode cache indexing (shared-position and ragged per-slot) --------
+# ---- decode cache (shared-position and ragged per-slot) -----------------
+# The stored K/V cache is [L, B, KV, hd, T]: per layer the [hd, T] operand
+# the decode dots read, with the sequence minor.  That is also the TPU's
+# own layout for such an array, so the step reads it in place and writes a
+# row into it without relaying out the whole cache.
+def to_cache_layout(x, seq_axis: int):
+    """K/V (or scales) [..., S, KV, hd] with S at ``seq_axis`` → the
+    cache's [..., KV, hd, S]."""
+    return jnp.moveaxis(x, seq_axis, -1)
+
+
 def cache_update(cache, new, pos):
-    """Write ``new`` [B, 1, ...] into ``cache`` [B, T, ...] at ``pos``.
+    """Write ``new`` [L, B, KV, hd, 1] into the layer-stacked ``cache``
+    [L, B, KV, hd, T] at ``pos``: every layer's row of a slot in one write.
 
     ``pos`` is either a scalar (all rows share one decode position — the
     fixed-batch path) or a [B] vector of per-slot positions (ragged
     continuous-batching decode, where every slot advances independently).
     """
-    new = new.astype(cache.dtype)
-    pos = jnp.asarray(pos, jnp.int32)
-    if pos.ndim == 0:
-        idx = (jnp.zeros((), jnp.int32), pos) + (jnp.zeros((), jnp.int32),
-                                                 ) * (cache.ndim - 2)
-        return lax.dynamic_update_slice(cache, new, idx)
-    return cache.at[jnp.arange(cache.shape[0]), pos].set(new[:, 0])
+    # A select over the whole cache: the step's output cache is a new
+    # buffer (its input is not donated), so the copy is paid anyway and the
+    # rows ride along in the same pass.  Along the minor sequence axis a
+    # scatter makes the TPU compiler relayout the whole cache around it,
+    # and an in-place update costs about as much as this copy (PERF.md).
+    B, T = cache.shape[1], cache.shape[-1]
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    hit = jnp.arange(T) == pos[:, None]                     # [B, T]
+    hit = hit.reshape((1, B) + (1,) * (cache.ndim - 3) + (T,))
+    return jnp.where(hit, new.astype(cache.dtype), cache)
 
 
 def decode_lengths(pos, batch: int):
-    """Valid KV length per row after writing at ``pos`` (scalar or [B])."""
-    pos = jnp.asarray(pos, jnp.int32)
-    if pos.ndim == 0:
-        return jnp.full((batch,), pos + 1, jnp.int32)
-    return pos + 1
+    """Cached KV length per row before the token decoded at ``pos``
+    (scalar or [B])."""
+    return jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (batch,))
 
 
 # ---- int8 KV-cache quantization (per-position, per-kv-head scales) ------
@@ -273,36 +285,60 @@ def kv_dequantize(q, scale, dtype=jnp.float32):
     return (q.astype(jnp.float32) * scale.astype(jnp.float32)).astype(dtype)
 
 
+def _decode_scores(qh, k, scale, softcap):
+    s = jnp.einsum("bkgh,bkht->bkgt", qh, k).astype(jnp.float32) * scale
+    if softcap > 0.0:
+        s = jnp.tanh(s / softcap) * softcap
+    return s
+
+
 def attention_decode(q, k_cache, v_cache, length: Optional[jax.Array] = None,
-                     softcap: float = 0.0, k_scale=None, v_scale=None):
-    """Single-token decode: q [B, 1, H, hd] vs caches [B, T, KV, hd]
-    (optionally int8 with per-position scales)."""
+                     softcap: float = 0.0, k_scale=None, v_scale=None,
+                     k_new=None, v_new=None):
+    """Single-token decode: q [B, 1, H, hd] vs caches [B, KV, hd, T]
+    (optionally int8 with per-position scales [B, KV, 1, T]).
+
+    With ``k_new``/``v_new`` [B, KV, hd, 1], the token's own key and value,
+    which the cache does not hold yet: it attends to the ``length`` cached
+    positions and to itself in one softmax, so the cache is only read."""
     if k_scale is not None:
         k_cache = kv_dequantize(k_cache, k_scale)
         v_cache = kv_dequantize(v_cache, v_scale)
     B, _, H, hd = q.shape
-    T, KV = k_cache.shape[1], k_cache.shape[2]
+    KV, T = k_cache.shape[1], k_cache.shape[-1]
     G = H // KV
     scale = 1.0 / math.sqrt(hd)
     qh = q.reshape(B, KV, G, hd)
-    s = jnp.einsum("bkgh,btkh->bkgt", qh, k_cache).astype(jnp.float32) * scale
-    if softcap > 0.0:
-        s = jnp.tanh(s / softcap) * softcap
+    s = _decode_scores(qh, k_cache, scale, softcap)
     if length is not None:
         valid = jnp.arange(T)[None, :] < length[:, None]    # [B, T]
         s = jnp.where(valid[:, None, None, :], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)
-    out = jnp.einsum("bkgt,btkh->bkgh", p, v_cache)
-    return out.reshape(B, 1, H, hd)
+    if k_new is None:
+        p = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)
+        out = jnp.einsum("bkgt,bkht->bkgh", p, v_cache)
+        return out.reshape(B, 1, H, hd)
+    s_new = _decode_scores(qh, k_new, scale, softcap)       # [B, KV, G, 1]
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), s_new)
+    e, e_new = jnp.exp(s - m), jnp.exp(s_new - m)
+    den = jnp.sum(e, axis=-1, keepdims=True) + e_new
+    p = (e / den).astype(v_cache.dtype)
+    p_new = (e_new / den).astype(v_cache.dtype)
+    out = (jnp.einsum("bkgt,bkht->bkgh", p, v_cache,
+                      preferred_element_type=jnp.float32)
+           + jnp.einsum("bkgt,bkht->bkgh", p_new, v_new,
+                        preferred_element_type=jnp.float32))
+    return out.astype(q.dtype).reshape(B, 1, H, hd)
 
 
 def flash_decode_sharded(q, k_cache, v_cache, ctx: ShardCtx,
-                         length: Optional[jax.Array] = None, *,
+                         length: Optional[jax.Array] = None, *, k_new, v_new,
                          seq_axes=None, batch_axes=(), k_scale=None,
                          v_scale=None):
-    """Distributed flash-decode: the KV cache sequence dim is sharded over
-    ``seq_axes``; each shard computes partial attention and the shards are
-    combined with a log-sum-exp reduction (shard_map + psum).
+    """Distributed flash-decode: the KV cache sequence dim (the last of
+    [B, KV, hd, T]) is sharded over ``seq_axes``; each shard computes
+    partial attention and the shards are combined with a log-sum-exp
+    reduction (shard_map + psum).  The token's own ``k_new``/``v_new`` (see
+    ``attention_decode``) join that combine as one more term.
 
     Two production uses:
       * long_500k — batch 1, seq over the data axes (seq_axes=ctx.dp)
@@ -310,62 +346,67 @@ def flash_decode_sharded(q, k_cache, v_cache, ctx: ShardCtx,
         (batch_axes=ctx.dp, seq_axes=('model',)) so the cache fits HBM even
         when GQA head counts don't divide the TP degree."""
     if not ctx.enabled:
-        return attention_decode(q, k_cache, v_cache, length)
+        return attention_decode(q, k_cache, v_cache, length, k_scale=k_scale,
+                                v_scale=v_scale, k_new=k_new, v_new=v_new)
     seq_axes = tuple(seq_axes if seq_axes is not None else ctx.dp)
     batch_axes = tuple(batch_axes)
 
     B, _, H, hd = q.shape
-    KV = k_cache.shape[2]
+    KV, T = k_cache.shape[1], k_cache.shape[-1]
     G = H // KV
     scale = 1.0 / math.sqrt(hd)
-    T = k_cache.shape[1]
     n_seq = ctx.axis_size(seq_axes)
     assert T % n_seq == 0, (T, seq_axes)
+    quant = k_scale is not None
 
-    def local(qh, kl, vl, lens, ks, vs):
-        # qh [b,KV,G,hd]; kl/vl [b, T/n, KV, hd]; all batch-local shards;
+    def local(qh, kl, vl, lens, *rest):
+        # qh [b,KV,G,hd]; kl/vl [b, KV, hd, T/n]; all batch-local shards;
         # int8 caches are dequantized per shard (tiny vs the full cache)
-        if ks is not None:
+        if quant:
+            ks, vs, *rest = rest
             kl = kv_dequantize(kl, ks)
             vl = kv_dequantize(vl, vs)
-        tl = kl.shape[1]
+        tl = kl.shape[-1]
         shard = jnp.zeros((), jnp.int32)
         for ax in seq_axes:
             shard = shard * ctx.mesh.shape[ax] + lax.axis_index(ax)
         kpos = shard * tl + jnp.arange(tl)
-        s = jnp.einsum("bkgh,btkh->bkgt", qh, kl).astype(jnp.float32) * scale
+        s = jnp.einsum("bkgh,bkht->bkgt", qh, kl).astype(jnp.float32) * scale
         if lens is not None:
             valid = kpos[None, :] < lens[:, None]
             s = jnp.where(valid[:, None, None, :], s, NEG_INF)
         m = jnp.max(s, axis=-1)                               # [b,KV,G]
         e = jnp.exp(s - m[..., None])
-        num = jnp.einsum("bkgt,btkh->bkgh", e, vl.astype(jnp.float32))
+        num = jnp.einsum("bkgt,bkht->bkgh", e, vl.astype(jnp.float32))
         den = jnp.sum(e, axis=-1)                             # [b,KV,G]
-        m_all = lax.pmax(m, seq_axes)
+        # the new token's term is the same on every shard: it joins after
+        # the reduction, once
+        kn, vn = rest
+        s_new = (jnp.einsum("bkgh,bkht->bkgt", qh, kn)[..., 0]
+                 .astype(jnp.float32) * scale)                  # [b,KV,G]
+        m_all = jnp.maximum(lax.pmax(m, seq_axes), s_new)
         c = jnp.exp(m - m_all)
-        num = lax.psum(num * c[..., None], seq_axes)
-        den = lax.psum(den * c, seq_axes)
+        e_new = jnp.exp(s_new - m_all)
+        num = (lax.psum(num * c[..., None], seq_axes)
+               + e_new[..., None] * vn[:, :, None, :, 0].astype(jnp.float32))
+        den = lax.psum(den * c, seq_axes) + e_new
         return (num / jnp.maximum(den, 1e-30)[..., None]).astype(q.dtype)
 
     qh = q.reshape(B, KV, G, hd)
     from jax.sharding import PartitionSpec as P
     bspec = batch_axes if batch_axes else None
     q_spec = P(bspec, None, None, None) if bspec else P()
-    kv_spec = P(bspec, seq_axes, None, None)
+    kv_spec = P(bspec, None, None, seq_axes)
     len_spec = P(bspec) if bspec else P()
-    if k_scale is None:
-        fn = lambda qh, kl, vl, lens: local(qh, kl, vl, lens, None, None)
-        out = jax.shard_map(
-            fn, mesh=ctx.mesh,
-            in_specs=(q_spec, kv_spec, kv_spec, len_spec),
-            out_specs=q_spec, check_vma=False,
-        )(qh, k_cache, v_cache, length)
-    else:
-        out = jax.shard_map(
-            local, mesh=ctx.mesh,
-            in_specs=(q_spec, kv_spec, kv_spec, len_spec, kv_spec, kv_spec),
-            out_specs=q_spec, check_vma=False,
-        )(qh, k_cache, v_cache, length, k_scale, v_scale)
+    args, specs = [qh, k_cache, v_cache, length], [q_spec, kv_spec, kv_spec,
+                                                   len_spec]
+    if quant:
+        args += [k_scale, v_scale]
+        specs += [kv_spec, kv_spec]
+    args += [k_new, v_new]
+    specs += [q_spec, q_spec]
+    out = jax.shard_map(local, mesh=ctx.mesh, in_specs=tuple(specs),
+                        out_specs=q_spec, check_vma=False)(*args)
     return out.reshape(B, 1, H, hd)
 
 

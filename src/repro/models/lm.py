@@ -115,39 +115,39 @@ class LM:
                                           softcap=cfg.logit_softcap)
             new_kv = (k, v)
         else:
-            # ``pos`` is a scalar (fixed-batch decode) or a [B] vector of
-            # per-slot positions (ragged continuous-batching decode);
-            # cache_update / decode_lengths handle both layouts
+            # ``cache`` is this layer's stored K/V, only read here: the
+            # token attends to its ``pos`` cached positions (``pos`` a
+            # scalar, or a [B] vector of per-slot positions for ragged
+            # decode) and to its own k/v, and returns that row in the
+            # cache's layout, which ``decode_step`` writes for every layer
+            # at once
             if self.kv_quant:
                 k_cache, v_cache, ks_cache, vs_cache = cache
                 kq, ks = L.kv_quantize(k)
                 vq, vs = L.kv_quantize(v)
-                k_cache = L.cache_update(k_cache, kq, pos)
-                v_cache = L.cache_update(v_cache, vq, pos)
-                ks_cache = L.cache_update(ks_cache, ks, pos)
-                vs_cache = L.cache_update(vs_cache, vs, pos)
-                scales = {"k_scale": ks_cache, "v_scale": vs_cache}
+                new_kv = tuple(L.to_cache_layout(a, 1)
+                               for a in (kq, vq, ks, vs))
+                k_new = L.kv_dequantize(new_kv[0], new_kv[2])
+                v_new = L.kv_dequantize(new_kv[1], new_kv[3])
+                kw = {"k_scale": ks_cache, "v_scale": vs_cache}
             else:
                 k_cache, v_cache = cache
-                k_cache = L.cache_update(k_cache, k, pos)
-                v_cache = L.cache_update(v_cache, v, pos)
-                scales = {"k_scale": None, "v_scale": None}
+                new_kv = k_new, v_new = (L.to_cache_layout(k, 1),
+                                         L.to_cache_layout(v, 1))
+                kw = {}
+            kw.update(k_new=k_new, v_new=v_new)
             length = L.decode_lengths(pos, x.shape[0])
             if ctx.enabled and ctx.decode_kv == "dp_seq":
                 out = L.flash_decode_sharded(q, k_cache, v_cache, ctx, length,
                                              seq_axes=ctx.dp, batch_axes=(),
-                                             **scales)
+                                             **kw)
             elif ctx.enabled and ctx.decode_kv == "tp_seq":
                 out = L.flash_decode_sharded(q, k_cache, v_cache, ctx, length,
                                              seq_axes=(ctx.tp,),
-                                             batch_axes=ctx.dp, **scales)
+                                             batch_axes=ctx.dp, **kw)
             else:
                 out = L.attention_decode(q, k_cache, v_cache, length,
-                                         cfg.logit_softcap, **scales)
-            if self.kv_quant:
-                new_kv = (k_cache, v_cache, ks_cache, vs_cache)
-            else:
-                new_kv = (k_cache, v_cache)
+                                         cfg.logit_softcap, **kw)
         out = jnp.einsum("bsq,qd->bsd",
                          out.reshape(x.shape[0], x.shape[1], -1), p["wo"])
         return out, new_kv
@@ -305,12 +305,13 @@ class LM:
         Lc, hd = cfg.n_layers, cfg.resolved_head_dim
         shapes: Dict[str, Any] = {}
         if cfg.family != "ssm":
-            kv = (Lc, batch, max_len, cfg.n_kv_heads, hd)
+            # the decode dots' layout, sequence minor (layers.py)
+            kv = (Lc, batch, cfg.n_kv_heads, hd, max_len)
             kv_dtype = jnp.int8 if self.kv_quant else self.dtype
             shapes["k"] = jax.ShapeDtypeStruct(kv, kv_dtype)
             shapes["v"] = jax.ShapeDtypeStruct(kv, kv_dtype)
             if self.kv_quant:
-                sc = (Lc, batch, max_len, cfg.n_kv_heads, 1)
+                sc = (Lc, batch, cfg.n_kv_heads, 1, max_len)
                 shapes["k_scale"] = jax.ShapeDtypeStruct(sc, jnp.bfloat16)
                 shapes["v_scale"] = jax.ShapeDtypeStruct(sc, jnp.bfloat16)
         if cfg.family == "hybrid":
@@ -331,11 +332,11 @@ class LM:
             # batch over dp; kv heads over tp when divisible.  'kv_seq' is
             # replicated by default; long_500k maps it to the dp axes and
             # flash_decode_sharded combines the shards (DESIGN.md §5).
-            ax["k"] = ("layer", "batch", "kv_seq", "kv_heads", None)
-            ax["v"] = ("layer", "batch", "kv_seq", "kv_heads", None)
+            ax["k"] = ("layer", "batch", "kv_heads", None, "kv_seq")
+            ax["v"] = ("layer", "batch", "kv_heads", None, "kv_seq")
             if self.kv_quant:
-                ax["k_scale"] = ("layer", "batch", "kv_seq", "kv_heads", None)
-                ax["v_scale"] = ("layer", "batch", "kv_seq", "kv_heads", None)
+                ax["k_scale"] = ("layer", "batch", "kv_heads", None, "kv_seq")
+                ax["v_scale"] = ("layer", "batch", "kv_heads", None, "kv_seq")
         if cfg.family == "hybrid":
             ax["conv"] = ("layer", "batch", None, "ffn")
             ax["ssm"] = ("layer", "batch", "heads", None, None)
@@ -376,18 +377,14 @@ class LM:
                                 gather=self.ctx.attn_impl != "cp")
         full = self.init_cache(B, max_len)
         if cfg.family != "ssm":
-            k_new, v_new = cache["k"], cache["v"]
+            new = {"k": cache["k"], "v": cache["v"]}     # [L, B, S, KV, hd]
             if self.kv_quant:
-                k_new, ks = L.kv_quantize(k_new)
-                v_new, vs = L.kv_quantize(v_new)
-                full["k_scale"] = lax.dynamic_update_slice(
-                    full["k_scale"], ks, (0, 0, 0, 0, 0))
-                full["v_scale"] = lax.dynamic_update_slice(
-                    full["v_scale"], vs, (0, 0, 0, 0, 0))
-            full["k"] = lax.dynamic_update_slice(
-                full["k"], k_new.astype(full["k"].dtype), (0, 0, 0, 0, 0))
-            full["v"] = lax.dynamic_update_slice(
-                full["v"], v_new.astype(full["v"].dtype), (0, 0, 0, 0, 0))
+                new["k"], new["k_scale"] = L.kv_quantize(new["k"])
+                new["v"], new["v_scale"] = L.kv_quantize(new["v"])
+            for key, x in new.items():
+                full[key] = lax.dynamic_update_slice(
+                    full[key], L.to_cache_layout(x, 2).astype(full[key].dtype),
+                    (0, 0, 0, 0, 0))
         for key in ("conv", "ssm", "wkv", "shift_tm", "shift_cm"):
             if key in full:
                 full[key] = cache[key].astype(full[key].dtype)
@@ -397,21 +394,33 @@ class LM:
         """token [B,1] int32; pos scalar int32 (current cache length) or a
         [B] int32 vector of per-slot cache lengths (ragged decode: each
         continuous-batching slot advances independently).
-        Returns (logits [B,1,V], new_cache)."""
+        Returns (logits [B,1,V], new_cache).
+
+        The stored K/V is no input or output of the layer scan: each layer
+        reads its slice of the stacked cache in place, and the scan emits
+        only the new rows, [L, B, KV, hd, 1], which one select per tensor
+        writes after it.  Recurrent state is small and goes through the
+        scan per layer."""
         x = self._embed(params, token)
         pos = jnp.asarray(pos, jnp.int32)
         if pos.ndim == 0:
             positions = jnp.full((1, 1), pos, jnp.int32)
         else:
             positions = pos[:, None]                     # [B, 1] per-slot
+        kv = {k: c for k, c in cache.items()
+              if k in ("k", "v", "k_scale", "v_scale")}
+        state = {k: c for k, c in cache.items() if k not in kv}
 
         def body(x, xs):
-            lp, cache_l = xs
-            x, new_cache_l, _ = self._block(x, lp, positions, "dec",
-                                            cache=cache_l, pos=pos)
-            return x, new_cache_l
+            lp, state_l, layer = xs
+            cache_l = dict(state_l, **{k: c[layer] for k, c in kv.items()})
+            x, new_l, _ = self._block(x, lp, positions, "dec",
+                                      cache=cache_l, pos=pos)
+            return x, new_l
 
-        x, new_cache = lax.scan(body, x, (params["layers"], cache))
+        x, new = lax.scan(body, x, (params["layers"], state,
+                                    jnp.arange(self.cfg.n_layers)))
         x = L.rms_norm(x, params["final_ln"], self.cfg.norm_eps)
         logits = self.logits_fn(params, x)
-        return logits, new_cache
+        new.update({k: L.cache_update(c, new[k], pos) for k, c in kv.items()})
+        return logits, new

@@ -111,7 +111,8 @@ class EncDecLM:
             v_cache = lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype),
                                                (0, pos, 0, 0))
             length = jnp.full((x.shape[0],), pos + 1, jnp.int32)
-            out = L.attention_decode(q, k_cache, v_cache, length)
+            out = L.attention_decode(q, jnp.moveaxis(k_cache, 1, -1),
+                                     jnp.moveaxis(v_cache, 1, -1), length)
             new_kv = (k_cache, v_cache)
         B, Sq = x.shape[:2]
         return jnp.einsum("bsq,qd->bsd", out.reshape(B, Sq, -1), pp["wo"]), new_kv

@@ -72,6 +72,9 @@ def test_sharded_train_step_matches_single_device():
 
 
 def test_flash_decode_sharded_matches_local():
+    """The sequence-sharded combine equals local decode attention over the
+    cache's [B, KV, hd, T] layout, the token's own k/v row joined: a full
+    cache, and ragged cached lengths with one of them empty."""
     run_subprocess("""
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.mesh import make_smoke_mesh
@@ -81,20 +84,30 @@ def test_flash_decode_sharded_matches_local():
         mesh = make_smoke_mesh()
         ctx = ShardCtx(mesh=mesh, dp=("data",), tp="model")
         rng = np.random.default_rng(0)
-        B, T, H, KV, hd = 1, 64, 8, 2, 16
-        q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), jnp.float32)
-        k = jnp.asarray(rng.standard_normal((B, T, KV, hd)), jnp.float32)
-        v = jnp.asarray(rng.standard_normal((B, T, KV, hd)), jnp.float32)
-        lens = jnp.full((B,), T, jnp.int32)
-        want = attention_decode(q, k, v, lens)
+        B, T, H, KV, hd = 2, 64, 8, 2, 16
+        f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+        q, k, v = f(B, 1, H, hd), f(B, KV, hd, T), f(B, KV, hd, T)
+        k_new, v_new = f(B, KV, hd, 1), f(B, KV, hd, 1)
+        full = jnp.full((B,), T, jnp.int32)
+        ragged = jnp.asarray([0, T - 3], jnp.int32)
+        new = {"k_new": k_new, "v_new": v_new}
         with jax.set_mesh(mesh):
-            k_sh = jax.device_put(k, NamedSharding(mesh, P(None, "data")))
-            v_sh = jax.device_put(v, NamedSharding(mesh, P(None, "data")))
-            got = jax.jit(lambda q, k, v, l:
-                          flash_decode_sharded(q, k, v, ctx, l))(q, k_sh,
-                                                                 v_sh, lens)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-4, atol=2e-4)
+            k_sh = jax.device_put(k, NamedSharding(mesh, P(None, None, None,
+                                                           "data")))
+            v_sh = jax.device_put(v, NamedSharding(mesh, P(None, None, None,
+                                                           "data")))
+            for lens in (full, ragged):
+                want = attention_decode(q, k, v, lens, **new)
+                got = jax.jit(lambda q, k, v, l, new:
+                              flash_decode_sharded(q, k, v, ctx, l, **new))(
+                    q, k_sh, v_sh, lens, new)
+                np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                           rtol=2e-4, atol=2e-4)
+        # the empty slot attends to its own row alone
+        np.testing.assert_allclose(
+            np.asarray(want[0, 0].reshape(KV, H // KV, hd)),
+            np.broadcast_to(np.asarray(v_new[0, :, None, :, 0]),
+                            (KV, H // KV, hd)), rtol=1e-6)
         print("FLASH_DECODE_OK")
     """)
 
