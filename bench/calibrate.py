@@ -45,7 +45,8 @@ def main(argv=None) -> int:
     print("setup: " + json.dumps(parts), flush=True)
     for seed in seeds:
         if params is None:
-            params = weights.make(cell.config["model"], seed)
+            m = cell.config["model"]
+            params = weights.make(cell.family.shapes(m), m, seed)
             server.params = params
         run = harness.serve_window(server, cell, seed=seed,
                                    seconds=args.seconds,
@@ -54,7 +55,7 @@ def main(argv=None) -> int:
         served = run.window.served
         row = {"seed": seed, "requests": len(served)}
         for control in (False, True):
-            chk = harness.reference_check(bench, cell, params, served, seed,
+            chk = harness.reference_check(cell, params, served, seed,
                                           control=control)
             row["control" if control else "program"] = {
                 **chk["readings"], "correct": chk["correct"]}

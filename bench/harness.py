@@ -106,6 +106,7 @@ class RunRecord:
     window_steps: Tuple[int, int]  # [first, last) step index in the window
     compiles_in_window: int
     trace: Optional[tracereduce.Trace]
+    family: Any = None             # references/<reference>.py: the counts
 
     def in_window(self, step: int) -> bool:
         return self.window_steps[0] <= step < self.window_steps[1]
@@ -126,23 +127,46 @@ class RunRecord:
         return out
 
 
+def _replace(base, given: Dict[str, Any], what: str):
+    """``base`` (a dataclass) with the fields ``given`` states; a key it
+    has no field for is an error."""
+    unknown = set(given) - {f.name for f in dataclasses.fields(base)}
+    if unknown:
+        raise KeyError(f"{what} keys unknown to {type(base).__name__}: "
+                       f"{sorted(unknown)}")
+    return dataclasses.replace(base, **given)
+
+
 def build_model_config(model: Dict[str, Any], arch: str):
     """The program's ``ModelConfig``: its registry entry for ``arch`` with
-    every field the configuration file states."""
+    every field the configuration file states.  A nested object (``moe``,
+    ``ssm``, ``encoder``) states fields of the registry entry's spec of
+    that name, which must have one."""
     from repro.configs import get_config
     base = get_config(arch)
-    fields = {f.name for f in dataclasses.fields(base)}
-    unknown = set(model) - fields
-    if unknown:
-        raise KeyError(f"model keys unknown to ModelConfig: {sorted(unknown)}")
-    return dataclasses.replace(base, **model)
+    model = dict(model)
+    for name, block in model.items():
+        if isinstance(block, dict):
+            spec = getattr(base, name, None)
+            if not dataclasses.is_dataclass(spec):
+                raise KeyError(f"model.{name}: {arch}'s registry entry has "
+                               f"no {name} spec")
+            model[name] = _replace(spec, block, f"model.{name}")
+    return _replace(base, model, "model")
+
+
+def _field(block: Dict[str, Any], dotted: str):
+    for part in dotted.split("."):
+        block = block[part]
+    return block
 
 
 def check_keymap(config: Dict[str, Any]) -> None:
     """The ``model`` block must state the same numbers as the source's
-    keys it names in ``keymap``."""
+    keys it names in ``keymap``; a dotted field (``moe.top_k``) names a
+    field of a nested spec block."""
     for field, key in config.get("keymap", {}).items():
-        want, got = config["config"][key], config["model"][field]
+        want, got = config["config"][key], _field(config["model"], field)
         if want != got:
             raise ValueError(f"{config['name']}: model.{field} = {got} but "
                              f"config.{key} = {want}")
@@ -260,12 +284,12 @@ def make_server(cell: Cell, params):
                          telemetry=ops.Telemetry())
 
 
-def reference_check(bench: Bench, cell: Cell, params, served: List[Served],
-                    seed: int, *, control: bool) -> Dict[str, Any]:
-    """The served tokens against the float32 reference; with ``control``,
-    the int8 reference's choices in the server's place, on the same
-    prompts and served tokens."""
-    ref = bench.reference(cell.config["reference"])
+def reference_check(cell: Cell, params, served: List[Served], seed: int, *,
+                    control: bool) -> Dict[str, Any]:
+    """The served tokens against the family's float32 reference; with
+    ``control``, the int8 reference's choices in the server's place, on
+    the same prompts and served tokens."""
+    ref = cell.family
     m = cell.config["model"]
     done = [s for s in served if s.req.done]
     chk = cell.settings["check"]
@@ -302,11 +326,14 @@ def generator_lag(window: Window) -> Dict[str, float]:
 
 def start_server(cell: Cell, seed: int, compiles: CompileLog
                  ) -> Tuple[Any, Any, Dict[str, Any]]:
-    """Weights from ``seed``, the server with its executables, and the
-    warm-up.  Returns (params, server, the set-up's parts)."""
+    """Weights from ``seed`` by the family's table, the server with its
+    executables, and the warm-up.  Returns (params, server, the set-up's
+    parts)."""
     import jax
+    m = cell.config["model"]
     t = time.perf_counter()
-    params = jax.block_until_ready(weights.make(cell.config["model"], seed))
+    params = jax.block_until_ready(weights.make(cell.family.shapes(m), m,
+                                                seed))
     t_weights = time.perf_counter()
     server = make_server(cell, params)
     t_exec = time.perf_counter()
@@ -401,8 +428,7 @@ def run_cell(bench: Bench, workload: str, *, seed: int, seconds: float,
     run.window.server = None
     del server
     gc.collect()
-    check = reference_check(bench, cell, params, served, seed,
-                            control=control)
+    check = reference_check(cell, params, served, seed, control=control)
     log("readings: " + " ".join(f"{k}={v}" for k, v in
                                 check["readings"].items()))
     del params
@@ -429,7 +455,7 @@ def run_cell(bench: Bench, workload: str, *, seed: int, seconds: float,
                         step_starts=run.window.step_starts,
                         window_steps=run.steps,
                         compiles_in_window=run.compiles_in_window,
-                        trace=tr if tr.devices else None)
+                        trace=tr if tr.devices else None, family=cell.family)
         metrics = {}
         for x in cell.per_layer:
             v = bench.metric_reader(x["name"])(rec)

@@ -1,8 +1,9 @@
 """Device: bytes the window's decode steps need (all weights once a step,
 plus the keys and values of live tokens only, not the cache's
-``max_len``; ``bench/flops.py``) over the decode executable's device time
-in the trace, as a share of the chip's HBM bandwidth."""
-from bench import flops, tracereduce
+``max_len``; the family's ``decode_step_bytes``,
+``references/<family>.py``) over the decode executable's device time in
+the trace, as a share of the chip's HBM bandwidth."""
+from bench import tracereduce
 
 
 def read(run):
@@ -12,6 +13,6 @@ def read(run):
     steps = run.window_decode_steps()
     if t <= 0 or not steps:
         return None
-    need = sum(flops.decode_step_bytes(run.model, keys)
+    need = sum(run.family.decode_step_bytes(run.model, keys)
                for keys in steps.values())
     return 100.0 * need / t / run.peaks["hbm_bytes_per_s"]

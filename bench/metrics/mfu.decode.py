@@ -1,7 +1,8 @@
 """Model step: operations the window's decoded tokens need, each at its
-own context length (``bench/flops.py``), over the device time of the
-decode executable in the trace, as a share of the chip's peak."""
-from bench import flops, tracereduce
+own context length (the family's ``decode_flops``,
+``references/<family>.py``), over the device time of the decode
+executable in the trace, as a share of the chip's peak."""
+from bench import tracereduce
 
 
 def read(run):
@@ -11,6 +12,6 @@ def read(run):
     steps = run.window_decode_steps()
     if t <= 0 or not steps:
         return None
-    need = sum(flops.decode_flops(run.model, k)
+    need = sum(run.family.decode_flops(run.model, k)
                for keys in steps.values() for k in keys)
     return 100.0 * need / t / run.peaks["bf16_flops_per_s"]

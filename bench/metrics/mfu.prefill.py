@@ -1,7 +1,8 @@
 """Model step: operations the window's true (unpadded) prompt tokens need
-(``bench/flops.py``) over the device time of the prefill executables in
-the trace, as a share of the chip's peak (``bench/peaks.json``)."""
-from bench import flops, tracereduce
+(the family's ``prefill_flops``, ``references/<family>.py``) over the
+device time of the prefill executables in the trace, as a share of the
+chip's peak (``bench/peaks.json``)."""
+from bench import tracereduce
 
 
 def read(run):
@@ -11,5 +12,5 @@ def read(run):
     prompts = run.window_prefills()
     if t <= 0 or not prompts:
         return None
-    need = sum(flops.prefill_flops(run.model, n) for n in prompts)
+    need = sum(run.family.prefill_flops(run.model, n) for n in prompts)
     return 100.0 * need / t / run.peaks["bf16_flops_per_s"]

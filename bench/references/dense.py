@@ -16,21 +16,105 @@ weights the benchmark made and the configuration's ``model`` block.
 product in int8 (weights per output column, activations per token,
 symmetric absmax scales, int32 accumulation), the step below the bf16
 the configurations state.
+
+It also holds the family's weight table (``shapes``) and the operations
+and bytes a served step needs (``prefill_flops``, ``decode_flops``,
+``decode_step_bytes``): the contract of ``bench/references/__init__.py``.
 """
 from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Dict
+from typing import Any, Dict, Iterable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from bench.flops import (BF16, attention_flops, head_dim, head_flops,
+                         kv_bytes_per_token)
+from bench.weights import padded_vocab
+
 HI = lax.Precision.HIGHEST
 F32 = jnp.float32
 BLOCK = 512
+Model = Dict[str, Any]
+
+
+def shapes(m: Model) -> Dict[str, Any]:
+    """Name -> (shape, std) of every parameter; std 0 means ones.
+
+    Every norm scale is one, as in a trained checkpoint, so random-weight
+    logits have a spread of about one and are not bf16 near-ties.
+    Matrices are normal with standard deviation ``1/sqrt(fan_in)``, the
+    embedding has standard deviation 1 and QKV biases 0.1, so the bias
+    path is exercised."""
+    L, d, f = m["n_layers"], m["d_model"], m["d_ff"]
+    hd = head_dim(m)
+    q, kv = m["n_heads"] * hd, m["n_kv_heads"] * hd
+    vp = padded_vocab(m)
+    layers = {
+        "ln1": ((L, d), 0.0), "ln2": ((L, d), 0.0),
+        "wq": ((L, d, q), d ** -0.5), "wk": ((L, d, kv), d ** -0.5),
+        "wv": ((L, d, kv), d ** -0.5), "wo": ((L, q, d), q ** -0.5),
+        "w1": ((L, d, f), d ** -0.5), "w3": ((L, d, f), d ** -0.5),
+        "w2": ((L, f, d), f ** -0.5),
+    }
+    if m.get("qkv_bias"):
+        layers.update({"bq": ((L, q), 0.1), "bk": ((L, kv), 0.1),
+                       "bv": ((L, kv), 0.1)})
+    top = {"embed": ((vp, d), 1.0), "final_ln": ((d,), 0.0)}
+    if not m.get("tie_embeddings"):
+        top["lm_head"] = ((d, vp), d ** -0.5)
+    return {"layers": layers, **top}
+
+
+def layer_matmul_params(m: Model) -> int:
+    """Weights one token multiplies through in one layer."""
+    d, hd = m["d_model"], head_dim(m)
+    q, kv = m["n_heads"] * hd, m["n_kv_heads"] * hd
+    n_mlp = 3 if m.get("act", "swiglu") == "swiglu" else 2
+    return d * (q + 2 * kv) + q * d + n_mlp * d * m["d_ff"]
+
+
+def layer_params(m: Model) -> int:
+    """Every weight of one layer: matrices, QKV biases, two norm scales."""
+    hd = head_dim(m)
+    bias = (m["n_heads"] + 2 * m["n_kv_heads"]) * hd if m.get("qkv_bias") \
+        else 0
+    return layer_matmul_params(m) + bias + 2 * m["d_model"]
+
+
+def prefill_flops(m: Model, prompt_len: int) -> int:
+    """A prompt of ``prompt_len`` true tokens, causal (position ``i``
+    attends ``i + 1`` keys), and the logits of its last position only."""
+    s = int(prompt_len)
+    return (2 * m["n_layers"] * layer_matmul_params(m) * s
+            + attention_flops(m, s * (s + 1) // 2) + head_flops(m))
+
+
+def decode_flops(m: Model, keys: int) -> int:
+    """One decoded token whose query attends ``keys`` cached keys (itself
+    included), and its logits."""
+    return (2 * m["n_layers"] * layer_matmul_params(m)
+            + attention_flops(m, keys) + head_flops(m))
+
+
+def weight_bytes(m: Model) -> int:
+    """Bytes of weights a decode step must read once: every layer, the
+    final norm and the output head over the real vocabulary (bf16)."""
+    return BF16 * (m["n_layers"] * layer_params(m) + m["d_model"]
+                   + m["d_model"] * m["vocab_size"])
+
+
+def decode_step_bytes(m: Model, keys: Iterable[int]) -> int:
+    """Bytes one decode step needs: the weights once, each live slot's
+    cached keys and values up to its own length (not the cache's
+    ``max_len``), its new entry written, and its embedding row read."""
+    keys = list(keys)
+    return (weight_bytes(m) + kv_bytes_per_token(m) * (sum(keys) + len(keys))
+            + BF16 * m["d_model"] * len(keys))
 
 
 def _rms(x, scale, eps):

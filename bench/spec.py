@@ -3,9 +3,11 @@
 Nothing here is specific to one cell: a configuration is
 ``configs/<config>.json``, a traffic mix ``traffic/<traffic>.json``, a
 cell's server settings and limits ``cells/<workload>.json``, a per-layer
-metric's reader ``metrics/<metric>.py``, a plain reference
-``references/<reference>.py`` and the device peaks ``peaks.json``.  A new
-cell, mix or metric is new files and new entries, never an edit.
+metric's reader ``metrics/<metric>.py``, a family's module
+``references/<reference>.py`` (weight table, plain reference and counts;
+its contract is ``references/__init__.py``'s docstring) and the device
+peaks ``peaks.json``.  A new cell, mix, metric or family is new files and
+new entries, never an edit.
 """
 from __future__ import annotations
 
@@ -41,6 +43,7 @@ class Cell:
     config: Dict[str, Any]        # configs/<config>.json
     traffic: Dict[str, Any]       # traffic/<traffic>.json
     settings: Dict[str, Any]      # cells/<workload>.json
+    family: Any                   # references/<config's reference>.py
     end_to_end: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
 
@@ -73,15 +76,17 @@ class Bench:
 
     def cell(self, name: str) -> Cell:
         w = self.workload(name)
+        config = self.config(w["config"])
         e2e = self.spec["end_to_end"]
         e2e_names = {m["name"] for m in e2e}
         per_layer = [m for m in self.spec["per_layer"]
                      if m["moves"] in e2e_names]
         return Cell(name=name, chips=int(w["chips"]),
-                    config=self.config(w["config"]),
+                    config=config,
                     traffic=_load_json(self._path(
                         "traffic", w["traffic"] + ".json")),
                     settings=_load_json(self._path("cells", name + ".json")),
+                    family=self.reference(config["reference"]),
                     end_to_end=e2e, per_layer=per_layer)
 
     def metric_reader(self, metric: str) -> Callable[[Any], Optional[float]]:
@@ -91,7 +96,7 @@ class Bench:
         return mod.read
 
     def reference(self, name: str):
-        """``references/<name>.py``: the plain float32 reference."""
+        """``references/<name>.py``: the family's module."""
         return _load_module(self._path("references", name + ".py"),
                             "bench_reference_" + name)
 

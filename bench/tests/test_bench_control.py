@@ -45,7 +45,7 @@ def test_control_reads_wider_gaps_than_the_program(bench):
                                        rate=cell.settings["rate_rps"],
                                        trace=False, compiles=compiles)
             for control in (False, True):
-                chk = harness.reference_check(bench, cell, params,
+                chk = harness.reference_check(cell, params,
                                               run.window.served, seed,
                                               control=control)
                 r = chk["readings"]

@@ -16,7 +16,7 @@ def test_reference_matches_the_program_in_float32(qkv_bias, partial):
     from repro.models import get_model
     m = dict(tiny.TINY_MODEL, param_dtype="float32", qkv_bias=qkv_bias,
              partial_rotary=partial)
-    params = weights.make(m, seed=5)
+    params = weights.make(dense.shapes(m), m, seed=5)
     model = get_model(build_model_config(m, "glm4-9b"))
     weights.check_layout(params, model.abstract_params())
     toks = np.random.default_rng(0).integers(0, m["vocab_size"], 40)
@@ -32,7 +32,7 @@ def test_reference_matches_the_program_in_float32(qkv_bias, partial):
 
 def test_int8_control_departs_from_the_reference():
     m = dict(tiny.TINY_MODEL)
-    params = weights.make(m, seed=6)
+    params = weights.make(dense.shapes(m), m, seed=6)
     toks = np.random.default_rng(1).integers(
         0, m["vocab_size"], 64).astype(np.int32)
     ref = dense.logits(params, m, toks, 0)

@@ -1,10 +1,14 @@
-"""A configuration, a traffic mix, a cell and a per-layer metric added as
-new files with new entries are found by name; no existing file changes."""
+"""A configuration, a traffic mix, a cell, a per-layer metric and a family
+added as new files with new entries are found by name; no existing file
+changes.  Spec blocks and dotted keymap fields of a configuration reach
+the program and are checked."""
 import hashlib
 import json
 import os
 
-from bench import spec
+import pytest
+
+from bench import harness, spec, weights
 from bench.tests import tiny
 
 
@@ -77,3 +81,57 @@ def test_benchmark_json_keys():
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
     for m in d["end_to_end"]:
         assert 0.01 <= m["bound"] <= 0.25
+
+
+def test_a_new_family_is_new_files_only(tmp_path):
+    """A family's module, configuration, mix and cell added to a copy of
+    the tree change no file it had, and are found by name."""
+    from repro.models import get_model
+    bench = tiny.make(str(tmp_path))
+    before = _digests(bench.dir)
+    tiny.add_moe(bench)
+    after = _digests(bench.dir)
+    assert {k: v for k, v in after.items() if k in before} == before
+    assert set(after) - set(before) == {
+        os.path.join("references", "tiny_moe.py"),
+        os.path.join("configs", "tiny_moe.json"),
+        os.path.join("traffic", "tiny_moe_chat.json"),
+        os.path.join("cells", tiny.MOE_WORKLOAD + ".json")}
+
+    cell = spec.Bench(root=bench.root, bench_dir=bench.dir).cell(
+        tiny.MOE_WORKLOAD)
+    assert cell.config["model"] == tiny.TINY_MOE_MODEL
+    assert cell.family.__file__ == os.path.join(bench.dir, "references",
+                                                "tiny_moe.py")
+    harness.check_keymap(cell.config)
+    m = cell.config["model"]
+    program = get_model(harness.build_model_config(m, cell.config["arch"]))
+    table = cell.family.shapes(m)
+    weights.check_layout(weights.make(table, m, seed=1),
+                         program.abstract_params())
+
+
+def test_spec_blocks_reach_the_program():
+    from repro.configs.base import MoESpec
+    cfg = harness.build_model_config(tiny.TINY_MOE_MODEL, "qwen2-moe-a2.7b")
+    assert cfg.moe == MoESpec(n_experts=8, top_k=2, d_ff_expert=32,
+                              n_shared=1, d_ff_shared=64,
+                              capacity_factor=4.0)
+    assert cfg.n_layers == 2 and cfg.family == "moe"
+    bad = dict(tiny.TINY_MOE_MODEL,
+               moe=dict(tiny.TINY_MOE_MODEL["moe"], n_group=2))
+    with pytest.raises(KeyError, match="n_group"):
+        harness.build_model_config(bad, "qwen2-moe-a2.7b")
+    with pytest.raises(KeyError, match="no moe spec"):
+        harness.build_model_config(tiny.TINY_MOE_MODEL, "glm4-9b")
+    with pytest.raises(KeyError, match="qk_scale"):
+        harness.build_model_config(dict(tiny.TINY_MODEL, qk_scale=1.0),
+                                   "glm4-9b")
+
+
+def test_dotted_keymap_compares_a_nested_field():
+    ok = tiny.TINY_MOE_CONFIG
+    harness.check_keymap(ok)
+    bad = dict(ok, config=dict(ok["config"], num_experts_per_tok=4))
+    with pytest.raises(ValueError, match="moe.top_k"):
+        harness.check_keymap(bad)
