@@ -21,8 +21,26 @@ class MoESpec:
     d_ff_expert: int
     n_shared: int = 0            # shared (always-on) experts
     d_ff_shared: int = 0         # hidden size of the fused shared expert
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # training's capacity dispatch only
     router_dtype: str = "float32"
+    # Under expert parallelism a chip holds ``held_experts`` experts (0:
+    # all of them) from index ``first_expert``; the router still spans
+    # all ``n_experts``, and picks of experts held elsewhere add nothing.
+    held_experts: int = 0
+    first_expert: int = 0
+    # the top-k gates renormalised to sum to one (the checkpoint's
+    # ``norm_topk_prob``), or the softmax's own probabilities
+    norm_topk_prob: bool = True
+
+    def __post_init__(self):
+        first, last = self.first_expert, self.first_expert + self.n_held
+        if not 0 <= first < last <= self.n_experts:
+            raise ValueError(f"held experts {first}..{last - 1} outside "
+                             f"0..{self.n_experts - 1}")
+
+    @property
+    def n_held(self) -> int:
+        return self.held_experts or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -120,7 +138,7 @@ class ModelConfig:
             expert = (3 if self.act == "swiglu" else 2) * d * m.d_ff_expert
             shared = (3 if self.act == "swiglu" else 2) * d * m.d_ff_shared if m.n_shared else 0
             router = d * m.n_experts
-            layer_total = attn + norms + router + m.n_experts * expert + shared
+            layer_total = attn + norms + router + m.n_held * expert + shared
             layer_active = attn + norms + router + m.top_k * expert + shared
             total = self.n_layers * layer_total
             active = self.n_layers * layer_active
@@ -163,7 +181,8 @@ class ModelConfig:
             kw["moe"] = dataclasses.replace(
                 self.moe, n_experts=4, top_k=2, d_ff_expert=32,
                 d_ff_shared=64 if self.moe.n_shared else 0,
-                n_shared=min(self.moe.n_shared, 1))
+                n_shared=min(self.moe.n_shared, 1), held_experts=0,
+                first_expert=0)
         if self.ssm is not None:
             kw["ssm"] = dataclasses.replace(self.ssm, state_dim=4, head_dim=16, chunk=8)
         if self.encoder is not None:

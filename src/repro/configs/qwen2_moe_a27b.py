@@ -1,4 +1,4 @@
-"""qwen2-moe-a2.7b — 60 routed experts top-4 + 4 shared [hf:Qwen/Qwen1.5-MoE-A2.7B; hf]."""
+"""qwen2-moe-a2.7b — 60 routed experts top-4 + 1 shared [hf:Qwen/Qwen1.5-MoE-A2.7B; hf]."""
 from repro.configs.base import ModelConfig, MoESpec
 
 CONFIG = ModelConfig(
@@ -16,8 +16,8 @@ CONFIG = ModelConfig(
     qkv_bias=True,
     tie_embeddings=False,
     norm_eps=1e-6,
+    # one shared expert of width 5632 behind a sigmoid gate
     moe=MoESpec(n_experts=60, top_k=4, d_ff_expert=1408,
-                n_shared=4, d_ff_shared=5632,  # 4 shared experts fused: 4×1408
-                capacity_factor=1.25),
+                n_shared=1, d_ff_shared=5632, capacity_factor=1.25),
     source="hf:Qwen/Qwen1.5-MoE-A2.7B",
 )

@@ -73,3 +73,10 @@ def grouped_matmul_ref(x, w):
     """x [E,M,K] @ w [E,K,N] → [E,M,N] (MoE expert GEMM)."""
     return jnp.einsum("emk,ekn->emn", x.astype(jnp.float32),
                       w.astype(jnp.float32)).astype(x.dtype)
+
+
+def ragged_matmul_ref(x, w, sizes):
+    """x [M,K] whose first ``sizes[0]`` rows take w[0], the next
+    ``sizes[1]`` w[1], ...; rows past ``sum(sizes)`` give zeros."""
+    return lax.ragged_dot(x, w, sizes.astype(jnp.int32),
+                          preferred_element_type=jnp.float32).astype(x.dtype)

@@ -447,16 +447,18 @@ def mlp(x, p, cfg: ModelConfig, ctx: ShardCtx):
 
 
 # --------------------------------------------------------------------------
-# Mixture of Experts (capacity-based per-sequence local dispatch)
+# Mixture of Experts
 # --------------------------------------------------------------------------
 def moe_param_spec(cfg: ModelConfig):
+    """The router spans every expert; the expert weights are this chip's
+    held block (``MoESpec.n_held`` of them, all by default)."""
     m = cfg.moe
-    d, fe = cfg.d_model, m.d_ff_expert
+    d, fe, eh = cfg.d_model, m.d_ff_expert, m.n_held
     spec = {
         "router": ((d, m.n_experts), ("d_model", "experts")),
-        "we1": ((m.n_experts, d, fe), ("experts", "d_model", "expert_ffn")),
-        "we2": ((m.n_experts, fe, d), ("experts", "expert_ffn", "d_model")),
-        "we3": ((m.n_experts, d, fe), ("experts", "d_model", "expert_ffn")),
+        "we1": ((eh, d, fe), ("experts", "d_model", "expert_ffn")),
+        "we2": ((eh, fe, d), ("experts", "expert_ffn", "d_model")),
+        "we3": ((eh, d, fe), ("experts", "d_model", "expert_ffn")),
     }
     if m.n_shared:
         spec.update({
@@ -468,90 +470,188 @@ def moe_param_spec(cfg: ModelConfig):
     return spec
 
 
+def moe_router_probs(x, p) -> jax.Array:
+    """Softmax over every expert of float32 router logits (the product
+    accumulated in float32, not a bf16 product cast after)."""
+    logits = jnp.einsum("bsd,de->bse", x, p["router"],
+                        preferred_element_type=jnp.float32)
+    return jax.nn.softmax(logits, axis=-1)
+
+
+def moe_route(x, p, m):
+    """The ``top_k`` experts of each token and their gates, renormalised
+    to sum to one where the checkpoint does (``norm_topk_prob``)."""
+    gate, eidx = lax.top_k(moe_router_probs(x, p), m.top_k)    # [B,S,K]
+    if m.norm_topk_prob:
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    return gate, eidx
+
+
 def _moe_capacity(S: int, m) -> int:
     c = int(math.ceil(S * m.top_k * m.capacity_factor / m.n_experts))
     return max(4, ((c + 3) // 4) * 4)
 
 
-def moe_block(x, p, cfg: ModelConfig, ctx: ShardCtx):
-    """x: [B, S, d].  Tokens are routed within their own sequence (B stays on
-    the data axes, so dispatch is communication-free); experts run as one
-    grouped einsum with the expert-ffn dim on the TP axis."""
+def _moe_capacity_shard_map(x, p, gate, eidx, cfg: ModelConfig,
+                            ctx: ShardCtx):
+    """Training's dispatch under a mesh: tokens routed within their own
+    sequence (B stays on the data axes, so dispatch is communication-free)
+    into ``capacity_factor``-sized expert buffers, a pick past its
+    expert's capacity dropped; the expert-ffn dim on the TP axis."""
+    from jax.sharding import PartitionSpec as P
     m = cfg.moe
+    if m.n_held != m.n_experts:
+        raise ValueError("the capacity dispatch computes every expert; "
+                         f"this layer holds {m.n_held} of {m.n_experts}")
     B, S, d = x.shape
     E, K = m.n_experts, m.top_k
     a = act_fn(cfg.act)
-
-    logits = jnp.einsum("bsd,de->bse", x, p["router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, eidx = lax.top_k(probs, K)                      # [B,S,K]
-    gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
     gate = gate.astype(x.dtype)
+    C = _moe_capacity(S, m)
+    ef = jnp.reshape(eidx, (B, S * K))                     # [B,T]
+    gf = jnp.reshape(gate, (B, S * K))
+    onehot = jax.nn.one_hot(ef, E, dtype=jnp.int32)        # [B,T,E]
+    pos = jnp.cumsum(onehot, axis=1) - onehot              # pos within expert
+    pos = jnp.sum(pos * onehot, axis=-1)                   # [B,T]
+    keep = (pos < C).astype(x.dtype)
+    xk = jnp.repeat(x, K, axis=1)                          # token s -> slots s*K+j
+    pos_c = jnp.minimum(pos, C - 1)
 
-    if S == 1:
-        # decode: all-expert dense compute then weighted combine
-        h = jnp.einsum("bsd,edf->bsef", x, p["we1"])
-        h = a(h) * jnp.einsum("bsd,edf->bsef", x, p["we3"])
-        ye = jnp.einsum("bsef,efd->bsed", h, p["we2"])    # [B,1,E,d]
-        w = jnp.sum(jax.nn.one_hot(eidx, E, dtype=x.dtype) * gate[..., None],
-                    axis=2)                                # [B,S,E]
-        out = jnp.einsum("bsed,bse->bsd", ye, w)
+    def scatter_one(buf, e_i, p_i, vals):
+        return buf.at[e_i, p_i].add(vals)
+
+    buf = jax.vmap(scatter_one)(
+        jnp.zeros((B, E, C, d), x.dtype), ef, pos_c, xk * keep[..., None])
+
+    def gather_one(y, e_i, p_i):
+        return y[e_i, p_i]
+
+    # combine-before-reduce: the expert-ffn output stays a PARTIAL sum
+    # over the tp-sharded expert-ffn dim; gathering per-token slots first
+    # means the psum moves [B,S,d] instead of the k·capacity× bigger
+    # [B,E,C,d] (§Perf, dbrx train)
+    def local(buf_l, w1, w3, w2, ef_l, pos_l, g_l):
+        h = jnp.einsum("becd,edf->becf", buf_l, w1)
+        h = a(h) * jnp.einsum("becd,edf->becf", buf_l, w3)
+        ye = jnp.einsum("becf,efd->becd", h, w2)           # [b,E,C,d]
+        yk = jax.vmap(gather_one)(ye, ef_l, pos_l) * g_l[..., None]
+        out_p = jnp.sum(yk.reshape(yk.shape[0], -1, K, d), axis=2)
+        return lax.psum(out_p, ctx.tp)
+
+    dp, tp = ctx.dp, ctx.tp
+    wspec = P(None, None, tp)
+    return jax.shard_map(
+        local, mesh=ctx.mesh,
+        in_specs=(P(dp, None, None, None), wspec, wspec, P(None, tp, None),
+                  P(dp, None), P(dp, None), P(dp, None)),
+        out_specs=P(dp, None, None), check_vma=False,
+    )(buf, p["we1"], p["we3"], p["we2"], ef, pos_c, gf * keep)
+
+
+def _moe_dropless(x, p, gate, eidx, cfg: ModelConfig, layer=None,
+                  first=None, sum_d=None, sum_f=None):
+    """Every pick that falls in the held block is computed, whatever the
+    load: the batch's picks sorted by held expert into whole row tiles,
+    the three expert matmuls as ragged grouped matmuls over the held
+    groups (``kernels/moe_gemm.py``), and each output weighted by its gate
+    and added back onto its token.  A pick of an expert this chip does
+    not hold adds nothing here: under expert parallelism that part is
+    another chip's.  With ``layer``, ``p``'s expert weights are the whole
+    layer stack, of which the kernel reads layer ``layer`` in place.
+
+    The held block is ``p``'s experts from ``first`` (the config's
+    ``first_expert`` by default).  ``sum_d`` and ``sum_f`` complete the
+    products whose contracted ``d_model`` or ``expert_ffn`` columns are
+    split over devices (``_moe_dropless_mesh``)."""
+    from repro.kernels import moe_gemm
+    m = cfg.moe
+    B, S, d = x.shape
+    K, G = m.top_k, p["we1"].shape[-3]
+    N = B * S
+    first = m.first_expert if first is None else first
+    sum_d = sum_d or (lambda t: t)
+    sum_f = sum_f or (lambda t: t)
+    local = eidx.reshape(N * K) - first
+    group = jnp.where((local >= 0) & (local < G), local, G)
+    tile_m = moe_gemm.row_tile(N * K)
+    dest, sizes, rows = moe_gemm.group_layout(group, G, tile_m)
+    # the token each buffer row holds (N, a zero row, for padding)
+    src = jnp.full((rows,), N, jnp.int32).at[dest].set(
+        jnp.arange(N * K, dtype=jnp.int32) // K, mode="drop")
+    xt = jnp.concatenate([x.reshape(N, d), jnp.zeros((1, d), x.dtype)])
+    buf = jnp.take(xt, src, axis=0)
+
+    def gmm(lhs, w):
+        if layer is None:
+            return moe_gemm.ragged_matmul(lhs, w, sizes, tile_m, None)
+        return moe_gemm.ragged_matmul_at(lhs, w, layer, sizes, tile_m)
+
+    h = act_fn(cfg.act)(sum_d(gmm(buf, p["we1"])))
+    h = h * sum_d(gmm(buf, p["we3"]))
+    y = sum_f(gmm(h, p["we2"]))                            # [rows, d]
+    # rows outside the groups are undefined: select, never multiply
+    yk = jnp.take(y, jnp.minimum(dest, rows - 1), axis=0).astype(jnp.float32)
+    yk = jnp.where((group < G)[:, None], yk * gate.reshape(N * K, 1), 0.0)
+    return jnp.sum(yk.reshape(B, S, K, d), axis=2).astype(x.dtype)
+
+
+def _moe_dropless_mesh(x, p, gate, eidx, cfg: ModelConfig, ctx: ShardCtx):
+    """The dropless dispatch under a mesh, inside ``shard_map`` on each
+    device's own shards of the expert weights, where they rest: a Pallas
+    call gives the SPMD partitioner nothing to split, so handed the
+    sharded weights it would gather them whole onto every device.
+
+    Each device routes every token (its ``d_model`` columns) over the
+    experts it holds; the products over split ``d_model`` and
+    ``expert_ffn`` columns are summed across those devices, and the
+    experts' outputs across the ``experts`` shards.  The output keeps the
+    ``d_model`` split of the weights."""
+    from jax.sharding import PartitionSpec as P
+    ep, dm, ff = (tuple(ctx.spec(("experts", "d_model", "expert_ffn"),
+                                 p["we1"].shape)) + (None,) * 3)[:3]
+
+    def over(axes):
+        return (lambda t: lax.psum(t, axes)) if axes else None
+
+    def local(x_l, gate_l, eidx_l, w1, w3, w2):
+        n = w1.shape[0]
+        first = cfg.moe.first_expert + (lax.axis_index(ep) * n if ep else 0)
+        out = _moe_dropless(x_l, {"we1": w1, "we3": w3, "we2": w2}, gate_l,
+                            eidx_l, cfg, first=first, sum_d=over(dm),
+                            sum_f=over(ff))
+        return lax.psum(out, ep) if ep else out
+
+    up, down = P(ep, dm, ff), P(ep, ff, dm)
+    return jax.shard_map(
+        local, mesh=ctx.mesh,
+        in_specs=(P(None, None, dm), P(), P(), up, up, down),
+        out_specs=P(None, None, dm), check_vma=False,
+    )(x, gate, eidx, p["we1"], p["we3"], p["we2"])
+
+
+EXPERT_WEIGHTS = ("we1", "we2", "we3")
+
+
+def moe_block(x, p, cfg: ModelConfig, ctx: ShardCtx, layer=None):
+    """x: [B, S, d].  The routed experts this chip holds, plus the shared
+    expert behind its sigmoid gate.  Routed dropless over the held
+    block: on one device (serving) directly, under a mesh on each
+    device's shards of the expert weights (``_moe_dropless_mesh``); a
+    sharded train step with ``moe_impl == "shard_map"`` keeps its
+    capacity dispatch.  With
+    ``layer`` (dropless only), ``p``'s ``EXPERT_WEIGHTS`` are stacked over
+    every layer and read at ``layer`` in place."""
+    m = cfg.moe
+    gate, eidx = moe_route(x, p, m)
+    if ctx.moe_impl == "shard_map" and ctx.enabled:
+        out = _moe_capacity_shard_map(x, p, gate, eidx, cfg, ctx)
+    elif ctx.enabled:
+        out = _moe_dropless_mesh(x, p, gate, eidx, cfg, ctx)
     else:
-        C = _moe_capacity(S, m)
-        ef = jnp.reshape(eidx, (B, S * K))                 # [B,T]
-        gf = jnp.reshape(gate, (B, S * K))
-        onehot = jax.nn.one_hot(ef, E, dtype=jnp.int32)    # [B,T,E]
-        pos = jnp.cumsum(onehot, axis=1) - onehot          # pos within expert
-        pos = jnp.sum(pos * onehot, axis=-1)               # [B,T]
-        keep = (pos < C).astype(x.dtype)
-        xk = jnp.repeat(x, K, axis=1)                      # token s -> slots s*K+j
-        pos_c = jnp.minimum(pos, C - 1)
-
-        def scatter_one(buf, e_i, p_i, vals):
-            return buf.at[e_i, p_i].add(vals)
-
-        buf = jax.vmap(scatter_one)(
-            jnp.zeros((B, E, C, d), x.dtype), ef, pos_c, xk * keep[..., None])
-
-        def gather_one(y, e_i, p_i):
-            return y[e_i, p_i]
-
-        def expert_ffn_combine(buf_l, w1, w3, w2, ef_l, pos_l, g_l):
-            h = jnp.einsum("becd,edf->becf", buf_l, w1)
-            h = a(h) * jnp.einsum("becd,edf->becf", buf_l, w3)
-            ye = jnp.einsum("becf,efd->becd", h, w2)       # [b,E,C,d]
-            yk = jax.vmap(gather_one)(ye, ef_l, pos_l) * g_l[..., None]
-            return jnp.sum(yk.reshape(yk.shape[0], -1, K, d), axis=2)
-
-        gk = gf * keep
-        if ctx.moe_impl == "shard_map" and ctx.enabled:
-            # combine-before-reduce: the expert-ffn output stays a PARTIAL
-            # sum over the tp-sharded expert-ffn dim; gathering per-token
-            # slots first means the psum moves [B,S,d] instead of the
-            # k·capacity× bigger [B,E,C,d] (§Perf, dbrx train)
-            from jax.sharding import PartitionSpec as P
-            tp = ctx.tp
-
-            def local(buf_l, w1, w3, w2, ef_l, pos_l, g_l):
-                out_p = expert_ffn_combine(buf_l, w1, w3, w2, ef_l, pos_l,
-                                           g_l)
-                return lax.psum(out_p, tp)
-
-            dp = ctx.dp
-            wspec = P(None, None, tp)
-            out = jax.shard_map(
-                local, mesh=ctx.mesh,
-                in_specs=(P(dp, None, None, None), wspec, wspec,
-                          P(None, tp, None), P(dp, None), P(dp, None),
-                          P(dp, None)),
-                out_specs=P(dp, None, None), check_vma=False,
-            )(buf, p["we1"], p["we3"], p["we2"], ef, pos_c, gk)
-        else:
-            buf = ctx.constrain(buf, "batch", "experts", None, None)
-            out = expert_ffn_combine(buf, p["we1"], p["we3"], p["we2"],
-                                     ef, pos_c, gk)
+        out = _moe_dropless(x, p, gate, eidx, cfg, layer)
 
     if m.n_shared:
+        a = act_fn(cfg.act)
         h = a(jnp.einsum("bsd,df->bsf", x, p["ws1"]))
         h = h * jnp.einsum("bsd,df->bsf", x, p["ws3"])
         sh = jnp.einsum("bsf,fd->bsd", h, p["ws2"])
@@ -564,8 +664,7 @@ def moe_block(x, p, cfg: ModelConfig, ctx: ShardCtx):
 def moe_aux_loss(x, p, cfg: ModelConfig) -> jax.Array:
     """Load-balancing auxiliary loss (Switch-style)."""
     m = cfg.moe
-    logits = jnp.einsum("bsd,de->bse", x, p["router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
+    probs = moe_router_probs(x, p)
     _, eidx = lax.top_k(probs, m.top_k)
     frac = jnp.mean(jax.nn.one_hot(eidx, m.n_experts, dtype=jnp.float32), axis=(0, 1, 2))
     imp = jnp.mean(probs, axis=(0, 1))

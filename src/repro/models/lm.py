@@ -153,8 +153,10 @@ class LM:
         return out, new_kv
 
     def _block(self, x, p, positions, mode, cache=None, pos=None,
-               want_aux=False):
-        """One transformer block.  Returns (x, new_cache, aux)."""
+               want_aux=False, layer=None):
+        """One transformer block.  Returns (x, new_cache, aux).  With
+        ``layer``, ``p``'s routed-expert weights are the whole stack
+        (``_split_experts``)."""
         cfg, ctx = self.cfg, self.ctx
         aux = jnp.zeros((), jnp.float32)
         if cfg.family == "ssm":
@@ -218,7 +220,7 @@ class LM:
         if cfg.family == "moe":
             if want_aux:
                 aux = L.moe_aux_loss(h2, p, cfg)
-            x = x + L.moe_block(h2, p, cfg, ctx)
+            x = x + L.moe_block(h2, p, cfg, ctx, layer=layer)
         else:
             x = x + L.mlp(h2, p, cfg, ctx)
         x = self.ctx.constrain(x, "batch", "seq" if mode == "par" else None, None)
@@ -231,23 +233,40 @@ class LM:
         x = jnp.take(params["embed"], tokens, axis=0).astype(self.dtype)
         return self.ctx.constrain(x, "batch", None, None)
 
+    def _split_experts(self, params, served: bool):
+        """(the weights the layer scan slices, the routed experts' weights
+        stacked over layers).  The second is filled for a served pass (not
+        differentiated) on one device, where the expert kernel reads each
+        layer's block where it lies: sliced by the scan, each layer's held
+        experts would be copied out whole first, all of them read whether
+        any token picked them or not."""
+        layers = params["layers"]
+        if not served or self.cfg.family != "moe" or self.ctx.enabled:
+            return layers, {}
+        whole = {k: layers[k] for k in L.EXPERT_WEIGHTS}
+        return {k: v for k, v in layers.items() if k not in whole}, whole
+
     def forward(self, params, tokens, *, want_aux=False, collect_cache=False):
         """Parallel forward over [B, S].  Returns (hidden, cache, aux)."""
         x = self._embed(params, tokens)
         positions = jnp.arange(tokens.shape[1])[None, :]
         mode = "par_cache" if collect_cache else "par"
+        layers, whole = self._split_experts(params, collect_cache)
 
-        def body(x, lp):
+        def body(x, xs):
+            lp, layer = xs
             # explicit FSDP gather: all-gather this layer's weights over the
             # data axes (reverse = gradient reduce-scatter)
             lp = self.ctx.gather_params(lp, self._layer_axes)
-            x, cache_l, aux = self._block(x, lp, positions, mode,
-                                          want_aux=want_aux)
+            x, cache_l, aux = self._block(
+                x, dict(lp, **whole), positions, mode, want_aux=want_aux,
+                layer=layer if whole else None)
             return x, (cache_l, aux)
 
         if self.remat:
             body = jax.checkpoint(body, prevent_cse=False)
-        x, (cache, auxs) = lax.scan(body, x, params["layers"])
+        x, (cache, auxs) = lax.scan(
+            body, x, (layers, jnp.arange(self.cfg.n_layers)))
         x = L.rms_norm(x, params["final_ln"], self.cfg.norm_eps)
         return x, cache, jnp.sum(auxs)
 
@@ -410,15 +429,17 @@ class LM:
         kv = {k: c for k, c in cache.items()
               if k in ("k", "v", "k_scale", "v_scale")}
         state = {k: c for k, c in cache.items() if k not in kv}
+        layers, whole = self._split_experts(params, True)
 
         def body(x, xs):
             lp, state_l, layer = xs
             cache_l = dict(state_l, **{k: c[layer] for k, c in kv.items()})
-            x, new_l, _ = self._block(x, lp, positions, "dec",
-                                      cache=cache_l, pos=pos)
+            x, new_l, _ = self._block(x, dict(lp, **whole), positions, "dec",
+                                      cache=cache_l, pos=pos,
+                                      layer=layer if whole else None)
             return x, new_l
 
-        x, new = lax.scan(body, x, (params["layers"], state,
+        x, new = lax.scan(body, x, (layers, state,
                                     jnp.arange(self.cfg.n_layers)))
         x = L.rms_norm(x, params["final_ln"], self.cfg.norm_eps)
         logits = self.logits_fn(params, x)

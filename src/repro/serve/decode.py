@@ -149,7 +149,9 @@ class BatchedServer:
         self.active: List[Optional[Request]] = [None] * slots
         self.finished: List[Request] = []
         self.pos = np.zeros(slots, np.int32)      # per-slot cache length
-        self.cache = model.init_cache(slots, max_len)
+        # a buffer of its own for each entry (eager zeros of one shape
+        # may share one), since the prefill donates each
+        self.cache = jax.tree.map(jnp.copy, model.init_cache(slots, max_len))
         self.swap_epochs = 0                      # hot-swap re-traces so far
         self.aot_compiles = 0                     # executables built so far
         self._rid = itertools.count()
@@ -228,8 +230,10 @@ class BatchedServer:
                                              mode="drop")
                 return first, jax.tree.map(put, cache, cache1)
 
+            # the cache is donated: the rows land in place, so the chip
+            # holds one cache beside the prefill's own buffers, not two
             ex = self._aot(
-                jax.jit(packed_prefill),
+                jax.jit(packed_prefill, donate_argnums=(3,)),
                 jax.ShapeDtypeStruct((n, bucket), jnp.int32),
                 jax.ShapeDtypeStruct((n,), jnp.int32),
                 self._cache_avals(),

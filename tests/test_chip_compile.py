@@ -22,7 +22,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.moe_gemm import grouped_matmul
+from repro.kernels.moe_gemm import (KERNEL_NAME, grouped_matmul,
+                                    ragged_matmul_at, row_tile)
 from repro.kernels.rwkv_wkv import wkv_pallas
 from repro.kernels.ssd_scan import ssd_pallas
 from repro.kernels.suites.pallas_lib import (elementwise_pallas,
@@ -79,6 +80,118 @@ def test_grouped_matmul_compiles_at_qwen2_moe_widths(one_chip):
     fn = functools.partial(grouped_matmul, interpret=False)
     assert "tpu_custom_call" in compile_for_chip(
         fn, [((E, 128, K), BF16), ((E, K, N), BF16)], one_chip)
+
+
+# the served expert layer of the benchmark's qwen2-moe cell: 15 held
+# experts of 2048 -> 1408 -> 2048 in each of 24 layers, one layer's read
+# out of the stack; a decode step of 24 slots x top-4 picks, and more
+# than the largest packed prefill, 32 rows x 256 x top-4
+@pytest.mark.parametrize("picks", [24 * 4, 32 * 256 * 4])
+@pytest.mark.parametrize("into", ["up", "down"])
+def test_ragged_matmul_compiles_at_the_held_share(one_chip, picks, into):
+    m = get_config("qwen2-moe-a2.7b")
+    G, d, f = 15, m.d_model, m.moe.d_ff_expert
+    K, N = (d, f) if into == "up" else (f, d)
+    tile_m = row_tile(picks)
+    rows = -(-picks // tile_m) * tile_m + G * tile_m
+    fn = functools.partial(ragged_matmul_at, tile_m=tile_m, interpret=False)
+    txt = compile_for_chip(
+        fn, [((rows, K), BF16), ((m.n_layers, G, K, N), BF16),
+             ((), jnp.int32), ((G,), jnp.int32)], one_chip)
+    assert "tpu_custom_call" in txt and KERNEL_NAME in txt
+
+
+def test_served_moe_decode_reads_the_expert_stack_in_place(one_chip,
+                                                          monkeypatch):
+    """The decode step hands the expert kernel every layer's weights at
+    once, so no layer's held experts are copied out before the kernel
+    reads the picked ones."""
+    import dataclasses
+
+    from repro.kernels import moe_gemm
+    from repro.models import get_model
+    # the model's kernels resolve to interpret mode on this CPU host
+    monkeypatch.setattr(moe_gemm, "resolve_interpret",
+                        lambda interpret=None: False)
+    c = get_config("qwen2-moe-a2.7b").reduced()
+    c = dataclasses.replace(c, moe=dataclasses.replace(
+        c.moe, held_experts=2, first_expert=1))
+    model = get_model(c)
+    B, T = 4, 64
+
+    def abstract(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = abstract(model.abstract_params())
+    cache = abstract(jax.eval_shape(lambda: model.init_cache(B, T)))
+    tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    txt = jax.jit(model.decode_step).lower(params, cache, tok,
+                                           pos).compile().as_text()
+    stack = params["layers"]["we1"].shape          # [L, held, d, f]
+    calls = [ln for ln in txt.splitlines()
+             if "tpu_custom_call" in ln and KERNEL_NAME in ln]
+    assert len(calls) == 3, calls
+    want = "bf16[" + ",".join(map(str, stack))
+    assert all(want in ln or "bf16[" + ",".join(
+        map(str, (stack[0], stack[1], stack[3], stack[2]))) in ln
+        for ln in calls), calls
+
+
+@pytest.mark.parametrize("preset", ["default", "ep"])
+def test_moe_decode_on_four_chips_reads_expert_shards_in_place(
+        topo, monkeypatch, preset):
+    """A decode step of qwen2-moe-a2.7b's expert layer (published widths,
+    two layers) sharded over a described 2x2 v5e: each of the three
+    expert kernels reads its device's shard of the weights where it
+    rests, and no collective gathers an expert weight whole (a Pallas
+    call gives the SPMD partitioner nothing to split)."""
+    import dataclasses
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.kernels import moe_gemm
+    from repro.launch.mesh import make_ctx
+    from repro.models import get_model
+    monkeypatch.setattr(moe_gemm, "resolve_interpret",
+                        lambda interpret=None: False)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    ctx = make_ctx(mesh, preset=preset)
+    c = dataclasses.replace(get_config("qwen2-moe-a2.7b"), n_layers=2)
+    model = get_model(c, ctx)
+    B, T = 8, 256
+
+    def abstract(tree, axes):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree, ctx.tree_shardings(axes, tree))
+
+    params = abstract(model.abstract_params(), model.param_axes())
+    rep = NamedSharding(mesh, P())
+    cache = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        jax.eval_shape(lambda: model.init_cache(B, T)))
+    tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=rep)
+    pos = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=rep)
+    with jax.set_mesh(mesh):
+        txt = jax.jit(model.decode_step).lower(params, cache, tok,
+                                               pos).compile().as_text()
+    E, d, f = c.moe.n_experts, c.d_model, c.moe.d_ff_expert
+    e_l, d_l, f_l = ((E // 2, d // 2, f) if preset == "ep"
+                     else (E, d // 2, f // 2))
+    calls = [ln for ln in txt.splitlines()
+             if "tpu_custom_call" in ln and KERNEL_NAME in ln]
+    assert len(calls) == 3, calls
+    shard = ("bf16[1,%d,%d,%d]" % (e_l, d_l, f_l),
+             "bf16[1,%d,%d,%d]" % (e_l, f_l, d_l))
+    assert all(any(s in ln for s in shard) for ln in calls), calls
+    gathered = [tuple(int(n) for n in g.split(",") if n) for g in
+                re.findall(r"= \w+\[([\d,]*)\][^=]*all-gather\(", txt)]
+    whole = {(E, d, f), (E, f, d), (2, E, d, f), (2, E, f, d)}
+    assert not whole & set(gathered), gathered
 
 
 def test_wkv_compiles_at_rwkv6_widths(one_chip):

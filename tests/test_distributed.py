@@ -196,3 +196,50 @@ def test_moe_dispatch_sharded_matches_single_device():
                                    rtol=3e-3, atol=3e-3)
         print("MOE_SHARDED_OK")
     """)
+
+
+@pytest.mark.parametrize("preset", ["default", "ep", "fsdp"])
+def test_moe_dropless_under_a_mesh_matches_single_device(preset):
+    """Under a mesh the dropless expert layer runs on each device's own
+    shards of the expert weights (``layers._moe_dropless_mesh``): a
+    forward pass and a decode step on a held share match the
+    single-device model.  That the shards stay in place is checked where
+    the kernel is a chip's custom call (``test_chip_compile.py``)."""
+    out = run_subprocess(f"""
+        import dataclasses
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.configs import get_config
+        from repro.models import get_model
+        from repro.launch.mesh import make_ctx, make_smoke_mesh
+
+        cfg = get_config("qwen2-moe-a2.7b").reduced()
+        cfg = dataclasses.replace(cfg, param_dtype="float32",
+                                  moe=dataclasses.replace(
+                                      cfg.moe, held_experts=2,
+                                      first_expert=1))
+        mesh = make_smoke_mesh()            # (4, 2): data x model
+        ctx = make_ctx(mesh, preset="{preset}")
+        m0, m1 = get_model(cfg), get_model(cfg, ctx)
+        params = m0.init_params(jax.random.PRNGKey(0))
+        sh = ctx.tree_shardings(m1.param_axes(), params)
+        params_sh = jax.tree.map(jax.device_put, params, sh)
+        toks = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0,
+                                  cfg.vocab_size)
+        want, _, _ = jax.jit(m0.forward)(params, toks)
+        B, T = 8, 32
+        cache = m0.init_cache(B, T)
+        tok = toks[:, :1]
+        pos = jnp.arange(B, dtype=jnp.int32)
+        want_d, _ = jax.jit(m0.decode_step)(params, cache, tok, pos)
+        with jax.set_mesh(mesh):
+            got, _, _ = jax.jit(m1.forward)(
+                params_sh,
+                jax.device_put(toks, NamedSharding(mesh, P("data", None))))
+            got_d, _ = jax.jit(m1.decode_step)(params_sh, cache, tok, pos)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=3e-3, atol=3e-3)
+        np.testing.assert_allclose(np.asarray(got_d), np.asarray(want_d),
+                                   rtol=3e-3, atol=3e-3)
+        print("MOE_MESH_OK")
+    """)
+    assert "MOE_MESH_OK" in out

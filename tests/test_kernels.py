@@ -8,7 +8,8 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.moe_gemm import grouped_matmul
+from repro.kernels.moe_gemm import (group_layout, grouped_matmul,
+                                    ragged_matmul)
 from repro.kernels.rwkv_wkv import wkv_pallas
 from repro.kernels.ssd_scan import ssd_pallas
 from repro.kernels.suites.pallas_lib import (elementwise_pallas,
@@ -110,6 +111,59 @@ def test_ssd_chunked_model_path_matches_ref():
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(st), np.asarray(want_st),
                                rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("G,P,K,N,tile_m", [
+    (5, 37, 64, 48, 16),          # decode-sized groups of a few rows
+    (4, 300, 128, 256, 128),      # groups over several row tiles
+    (3, 8, 256, 1408, 16),        # the expert width: one block of N
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_ragged_matmul_sweep(G, P, K, N, tile_m, dtype):
+    """Each routed row times its own group's weight; a group with no rows
+    (group 1 here) gets no row tile, and picks outside the held groups
+    (id ``G``) get no row of the buffer."""
+    group = jnp.asarray(RNG.choice([0] + list(range(2, G + 1)), size=P),
+                        jnp.int32)
+    dest, sizes, rows = group_layout(group, G, tile_m)
+    assert int(sizes[1]) == 0 and (np.asarray(sizes) % tile_m == 0).all()
+    held = np.asarray(group) < G
+    assert sorted(np.asarray(dest)[held]) == sorted(set(
+        np.asarray(dest)[held])) and (np.asarray(dest)[~held] == rows).all()
+    x, w = randn((P, K), dtype), randn((G, K, N), dtype)
+    buf = jnp.zeros((rows, K), dtype).at[dest].set(x, mode="drop")
+    got = np.asarray(ragged_matmul(buf, w, sizes, tile_m, None), np.float32)
+    want = np.einsum("pk,pkn->pn", np.asarray(x, np.float32), np.asarray(
+        w, np.float32)[np.minimum(np.asarray(group), G - 1)])
+    tol = TOL[dtype] * K ** 0.5
+    np.testing.assert_allclose(got[np.asarray(dest)[held]], want[held],
+                               rtol=tol, atol=tol)
+    n = int(sizes.sum())
+    np.testing.assert_allclose(
+        got[:n], np.asarray(ref.ragged_matmul_ref(buf, w, sizes),
+                            np.float32)[:n], rtol=tol, atol=tol)
+
+
+def test_ragged_matmul_gradient_is_the_references():
+    G, tile_m = 3, 16
+    group = jnp.asarray(RNG.integers(0, G + 1, size=40), jnp.int32)
+    dest, sizes, rows = group_layout(group, G, tile_m)
+    buf = jnp.zeros((rows, 32)).at[dest].set(randn((40, 32)), mode="drop")
+    w = randn((G, 32, 24))
+    n = int(sizes.sum())
+
+    def loss(f):
+        return lambda b, w_: jnp.sum(jnp.sin(f(b, w_)[:n]))
+
+    got = jax.grad(loss(lambda b, w_: ragged_matmul(b, w_, sizes, tile_m,
+                                                    None)),
+                   argnums=(0, 1))(buf, w)
+    want = jax.grad(loss(lambda b, w_: ref.ragged_matmul_ref(b, w_, sizes)),
+                    argnums=(0, 1))(buf, w)
+    for g, h in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(h), rtol=1e-4,
+                                   atol=1e-4)
 
 
 # ---------------------------------------------------------------------------

@@ -60,8 +60,15 @@ def test_context_parallel_forward_matches(arch):
 
 def test_moe_shard_map_combine_matches_einsum():
     run_subprocess("""
-        cfg = dataclasses.replace(get_config("dbrx-132b").reduced(),
-                                  param_dtype="float32")
+        # the single-device model routes dropless; a capacity of every
+        # pick an expert could get keeps the capacity dispatch from
+        # dropping any, so the two agree
+        cfg = get_config("dbrx-132b").reduced()
+        cfg = dataclasses.replace(cfg, param_dtype="float32",
+                                  moe=dataclasses.replace(
+                                      cfg.moe, capacity_factor=float(
+                                          cfg.moe.n_experts)
+                                      / cfg.moe.top_k))
         mesh = make_smoke_mesh()
         m0 = get_model(cfg)
         params = m0.init_params(jax.random.PRNGKey(0))
