@@ -29,6 +29,14 @@ greedy decode continuously:
   the request's bucket, so each (site, bucket) pair is a distinct
   telemetry site and ``serve.autotune`` campaigns per traffic bucket at
   that bucket's observed scale.
+* **One step ahead** — step call k dispatches its admission prefills
+  and decode k before it reads decode k−1's tokens and the prefills'
+  first tokens back, so the host's read-back and bookkeeping run while
+  decode k runs on the device.  Decode k's token input stays on the
+  device: decode k−1's picks with each admitted prompt's first token
+  spliced in by its prefill.  Positions and budget finishes are known on
+  the host at dispatch; only an EOS is learned one step late, and the
+  one token its slot decodes past it is dropped.
 * **Profiler spans** — ``submit`` and every phase of ``step`` open a
   ``jax.profiler.TraceAnnotation`` named ``serve.*``, so a profiler
   trace shows on the device's clock what the host did in each idle gap.
@@ -148,12 +156,25 @@ class BatchedServer:
         self.queue: List[Request] = []
         self.active: List[Optional[Request]] = [None] * slots
         self.finished: List[Request] = []
-        self.pos = np.zeros(slots, np.int32)      # per-slot cache length
+        # per-slot cache length, advanced when a decode is dispatched
+        self.pos = np.zeros(slots, np.int32)
         # a buffer of its own for each entry (eager zeros of one shape
         # may share one), since the prefill donates each
         self.cache = jax.tree.map(jnp.copy, model.init_cache(slots, max_len))
         self.swap_epochs = 0                      # hot-swap re-traces so far
         self.aot_compiles = 0                     # executables built so far
+        # decodes dispatched while the previous decode's tokens were
+        # still on the device
+        self.decodes_ahead = 0
+        # tokens decoded for a request already finished when they were
+        # read: the one step past an EOS learned late
+        self.dropped_tokens = 0
+        # the token each slot feeds its next decode, on the device
+        self._feed = jax.device_put(np.zeros(slots, np.int32))
+        # the decode whose tokens are not read yet: its picks and the
+        # (slot, request) of each row it decoded
+        self._ahead: Optional[Tuple[jax.Array,
+                                    List[Tuple[int, Request]]]] = None
         self._rid = itertools.count()
         self._epoch = ops.registry_epoch()
         self._exec: Dict[Tuple, object] = {}      # (kind, ...) -> executable
@@ -166,6 +187,7 @@ class BatchedServer:
         takes effect here and only here."""
         self._exec.clear()
         self._get_decode()
+        self._get_decode_input()
         if self.aot and self.padded_packing:
             n = 1
             while n <= _next_pow2(self.slots):
@@ -177,14 +199,14 @@ class BatchedServer:
         return jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.cache)
 
-    def _aot(self, jitted, *avals):
-        """AOT-compile ``jitted`` for ``avals``; a compile error propagates
-        (a kernel the chip's compiler refuses must not be served by some
-        other path).  With ``aot=False`` the jit object is returned as-is
-        and compiles lazily on first call."""
+    def _aot(self, jitted, *args):
+        """AOT-compile ``jitted`` for ``args`` (arrays or avals); a compile
+        error propagates (a kernel the chip's compiler refuses must not be
+        served by some other path).  With ``aot=False`` the jit object is
+        returned as-is and compiles lazily on first call."""
         if not self.aot:
             return jitted
-        ex = jitted.lower(self.params, *avals).compile()
+        ex = jitted.lower(*args).compile()
         self.aot_compiles += 1
         return ex
 
@@ -202,9 +224,23 @@ class BatchedServer:
                                    axis=-1).astype(jnp.int32), cache)
 
             ex = self._aot(
-                jax.jit(decode_and_pick), self._cache_avals(),
+                jax.jit(decode_and_pick), self.params, self._cache_avals(),
                 jax.ShapeDtypeStruct((self.slots, 1), jnp.int32),
                 jax.ShapeDtypeStruct((self.slots,), jnp.int32))
+            self._exec[key] = ex
+        return ex
+
+    def _get_decode_input(self):
+        """``[slots]`` tokens on the device -> the decode's ``[slots, 1]``
+        token input, without a round trip through the host."""
+        key = ("decode_input",)
+        ex = self._exec.get(key)
+        if ex is None:
+            def decode_input(feed):
+                return feed[:, None]
+
+            ex = self._aot(jax.jit(decode_input), jax.ShapeDtypeStruct(
+                (self.slots,), jnp.int32))
             self._exec[key] = ex
         return ex
 
@@ -215,10 +251,11 @@ class BatchedServer:
             model, max_len = self.model, self.max_len
             vocab = model.cfg.vocab_size
 
-            def packed_prefill(params, toks, lens, cache, si):
+            def packed_prefill(params, toks, lens, cache, si, feed):
                 # prefill + greedy pick + slot splice fused into one
-                # executable: row r lands in cache slot si[r]; pad rows
-                # carry an out-of-range index and are dropped
+                # executable: row r lands in cache slot si[r], and its
+                # first token becomes that slot's next decode input; pad
+                # rows carry an out-of-range index and are dropped
                 logits, cache1 = model.prefill(params, toks,
                                                max_len=max_len,
                                                lengths=lens)
@@ -228,16 +265,20 @@ class BatchedServer:
                 def put(big, one):
                     return big.at[:, si].set(one.astype(big.dtype),
                                              mode="drop")
-                return first, jax.tree.map(put, cache, cache1)
+                return (first, feed.at[si].set(first, mode="drop"),
+                        jax.tree.map(put, cache, cache1))
 
             # the cache is donated: the rows land in place, so the chip
-            # holds one cache beside the prefill's own buffers, not two
+            # holds one cache beside the prefill's own buffers, not two.
+            # The feed is not: it is the last decode's picks, which the
+            # host reads after this call
+            vec = jax.ShapeDtypeStruct((self.slots,), jnp.int32)
             ex = self._aot(
-                jax.jit(packed_prefill, donate_argnums=(3,)),
+                jax.jit(packed_prefill, donate_argnums=(3,)), self.params,
                 jax.ShapeDtypeStruct((n, bucket), jnp.int32),
                 jax.ShapeDtypeStruct((n,), jnp.int32),
                 self._cache_avals(),
-                jax.ShapeDtypeStruct((n,), jnp.int32))
+                jax.ShapeDtypeStruct((n,), jnp.int32), vec)
             self._exec[key] = ex
         return ex
 
@@ -270,119 +311,161 @@ class BatchedServer:
             self.queue.append(req)
         return req
 
+    def _budget(self, req: Request) -> int:
+        """Tokens ``req`` is served unless it meets EOS first: its
+        ``max_new``, or fewer where its answer would overrun the cache."""
+        return min(req.max_new, self.max_len - len(req.prompt) + 1)
+
+    def _decodes(self, s: int) -> bool:
+        """Slot ``s`` holds a request with a decode left to dispatch.  A
+        slot whose request has all its tokens dispatched is free, even
+        while its last token is still on the device: a prefill into it
+        runs after that decode, whose cache it takes."""
+        req = self.active[s]
+        return req is not None and \
+            int(self.pos[s]) - len(req.prompt) + 1 < self._budget(req)
+
     def _finish(self, req: Request, slot: Optional[int]) -> None:
         req.done = True
         self.finished.append(req)
-        if slot is not None:
+        if slot is not None and self.active[slot] is req:
             self.active[slot] = None          # slot recycled at next admit
             self.pos[slot] = 0
 
-    def _admit(self) -> int:
+    def _admit(self) -> List[Tuple[jax.Array, List[Tuple[Optional[int],
+                                                         Request]]]]:
         """Drain the queue into free slots, one packed prefill call per
-        bucket per wave.  Returns the number of requests admitted."""
-        admitted = 0
+        bucket per wave.  Returns each call's first tokens, still on the
+        device, with the (slot, request) of each row; a request that ends
+        at its first token takes no slot."""
+        calls = []
         while self.queue:
-            free = [s for s in range(self.slots) if self.active[s] is None]
+            free = [s for s in range(self.slots) if not self._decodes(s)]
             if not free:
                 break
             wave, rest = self.queue[:len(free)], self.queue[len(free):]
             self.queue = rest
-            admitted += len(wave)
             groups: Dict[int, List[Request]] = {}
             for req in wave:                  # FIFO within each bucket
                 groups.setdefault(req.bucket, []).append(req)
             fi = 0
-            finished_at_prefill = False
+            done_at_prefill = False
             for bucket, reqs in groups.items():
                 n_pad = _next_pow2(len(reqs))  # bounded executable count
                 with TraceAnnotation("serve.prefill"):
                     toks = np.zeros((n_pad, bucket), np.int32)
                     lens = np.ones((n_pad,), np.int32)
-                    # tentative slot per row; pad rows point past the
-                    # pool and are dropped by the in-executable splice.
-                    # A row whose request finishes at its prefill token
-                    # simply leaves garbage in a slot that stays free —
-                    # dead slots are masked at decode and overwritten on
-                    # re-admission.
+                    # pad rows, and rows of requests that end at their
+                    # prefill token, point past the pool: the splice in
+                    # the executable drops them
                     si = np.full((n_pad,), self.slots, np.int32)
+                    rows: List[Tuple[Optional[int], Request]] = []
                     for r, req in enumerate(reqs):
                         toks[r, :len(req.prompt)] = req.prompt
                         lens[r] = len(req.prompt)
-                        si[r] = free[fi]
+                        if self._budget(req) <= 1:
+                            rows.append((None, req))
+                            done_at_prefill = True
+                            continue
+                        s = si[r] = free[fi]
                         fi += 1
-                    first, self.cache = self._get_prefill(bucket, n_pad)(
+                        rows.append((s, req))
+                        self.active[s] = req
+                        self.pos[s] = len(req.prompt)
+                    first, self._feed, self.cache = self._get_prefill(
+                        bucket, n_pad)(
                         self.params, jnp.asarray(toks), jnp.asarray(lens),
-                        self.cache, jnp.asarray(si))
-                with TraceAnnotation("serve.prefill_wait"):
-                    first = np.asarray(first)
-                for r, req in enumerate(reqs):
-                    tok = int(first[r])
-                    req.tokens.append(tok)
-                    self.telemetry.observe(
-                        self.site, scale=len(req.prompt),
-                        tokens=len(req.prompt), kind="prefill",
-                        bucket=bucket)
-                    if ((self.eos_id is not None and tok == self.eos_id)
-                            or len(req.tokens) >= req.max_new):
-                        self._finish(req, None)  # done at prefill
-                        finished_at_prefill = True
-                        continue
-                    self.active[si[r]] = req
-                    self.pos[si[r]] = len(req.prompt)
-            if not finished_at_prefill:
-                break                         # all tentative slots taken
-            # some requests finished at prefill: their slots are still
-            # free, loop to admit more while the queue has work
-        return admitted
+                        self.cache, jnp.asarray(si), self._feed)
+                    # start the read-back now: it waits for this call,
+                    # not for the decode queued behind it
+                    first.copy_to_host_async()
+                calls.append((first, rows))
+            if not done_at_prefill:
+                break                         # every slot given is taken
+            # some requests took no slot: loop to admit more while the
+            # queue has work
+        return calls
+
+    def _take(self, req: Request, tok: int, slot: Optional[int]) -> None:
+        """Append a token read back from the device to ``req`` and finish
+        it at EOS or at its budget; drop it where ``req`` has finished
+        already (its EOS was read one step late)."""
+        if req.done:
+            self.dropped_tokens += 1
+            return
+        req.tokens.append(tok)
+        if ((self.eos_id is not None and tok == self.eos_id)
+                or len(req.tokens) >= self._budget(req)):
+            self._finish(req, slot)           # EOS / budget / cache full
 
     # ------------------------------------------------------------- steps --
     def step(self) -> int:
-        """One serving step: admit (packed prefill per bucket), then one
-        ragged decode over every occupied slot.  Returns the amount of
-        work done — requests admitted plus tokens decoded — so ``0``
-        means the server is idle (queue empty, no live slots)."""
+        """One serving step, one step ahead: admit (packed prefill per
+        bucket) and dispatch one ragged decode over every slot with a
+        token left to decode, then read back the previous step's decode
+        and this step's prefills and append their tokens while the new
+        decode runs.  Returns the amount of work done — requests
+        admitted, tokens dispatched and tokens read — so ``0`` means the
+        server is idle (queue empty, no live slots, nothing in flight)."""
         with TraceAnnotation("serve.step"):
             self._refresh_impls()
-            worked = self._admit()
-            live = [s for s in range(self.slots)
-                    if self.active[s] is not None]
-            if not live:
-                return worked
-            with TraceAnnotation("serve.decode"):
-                toks = np.zeros((self.slots, 1), np.int32)
-                for s in live:
-                    toks[s, 0] = self.active[s].tokens[-1]
-                # per-slot positions: dead slots decode a dummy token at
-                # pos 0 (their row is fully overwritten at the next
-                # admission)
-                nxt, self.cache = self._get_decode()(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(self.pos))
-            with TraceAnnotation("serve.decode_wait"):
-                nxt = np.asarray(nxt)
+            prefills = self._admit()
+            live = [s for s in range(self.slots) if self._decodes(s)]
+            ahead, self._ahead = self._ahead, None
+            if live:
+                with TraceAnnotation("serve.decode"):
+                    # per-slot positions: a slot with nothing to decode
+                    # decodes a dummy token at pos 0 (its row is fully
+                    # overwritten at the next admission)
+                    pos = np.zeros(self.slots, np.int32)
+                    pos[live] = self.pos[live]
+                    nxt, self.cache = self._get_decode()(
+                        self.params, self.cache,
+                        self._get_decode_input()(self._feed),
+                        jnp.asarray(pos))
+                    nxt.copy_to_host_async()
+                    self._feed = nxt
+                    self.pos[live] += 1
+                    self.decodes_ahead += ahead is not None
+                    self._ahead = (nxt, [(s, self.active[s]) for s in live])
+            if ahead is not None:
+                with TraceAnnotation("serve.decode_wait"):
+                    picked = np.asarray(ahead[0])
+            firsts = []
+            if prefills:
+                with TraceAnnotation("serve.prefill_wait"):
+                    firsts = [np.asarray(first) for first, _ in prefills]
+            if ahead is None and not prefills:
+                return len(live)
             with TraceAnnotation("serve.bookkeeping"):
-                for s in live:
-                    req = self.active[s]
-                    tok = int(nxt[s])
-                    req.tokens.append(tok)
-                    self.pos[s] += 1
-                    # context length this token was decoded at (traffic
-                    # weighting)
-                    self.telemetry.observe(self.site, scale=int(self.pos[s]),
-                                           tokens=1, kind="decode",
-                                           bucket=req.bucket)
-                    if ((self.eos_id is not None and tok == self.eos_id)
-                            or len(req.tokens) >= req.max_new
-                            or int(self.pos[s]) >= self.max_len):
-                        self._finish(req, s)  # EOS / budget / cache full
-            return worked + len(live)
+                if ahead is not None:
+                    for s, req in ahead[1]:
+                        tok = int(picked[s])
+                        if not req.done:
+                            # context length this token was decoded at
+                            # (traffic weighting)
+                            self.telemetry.observe(
+                                self.site,
+                                scale=len(req.prompt) + len(req.tokens),
+                                tokens=1, kind="decode", bucket=req.bucket)
+                        self._take(req, tok, s)
+                for first, (_, rows) in zip(firsts, prefills):
+                    for r, (s, req) in enumerate(rows):
+                        self.telemetry.observe(
+                            self.site, scale=len(req.prompt),
+                            tokens=len(req.prompt), kind="prefill",
+                            bucket=req.bucket)
+                        self._take(req, int(first[r]), s)
+            return (sum(len(rows) for _, rows in prefills) + len(live)
+                    + (len(ahead[1]) if ahead is not None else 0))
 
     def run(self, max_steps: int = 1000) -> List[Request]:
-        """Drive steps until the queue *and* the slots are both drained
-        (a step that only admits-and-finishes-at-prefill keeps going
-        while the queue has work)."""
+        """Drive steps until the queue, the slots and the decode in flight
+        are all drained (a step that only admits-and-finishes-at-prefill
+        keeps going while the queue has work)."""
         for _ in range(max_steps):
-            if not self.queue and all(a is None for a in self.active):
+            if not self.queue and self._ahead is None and all(
+                    a is None for a in self.active):
                 break
             self.step()
         return self.finished

@@ -51,12 +51,14 @@ def test_serving_and_reintegration_phases_on_reduced_preset(smoke,
     assert any("tokens equal to generate(): 64/64" in ln for ln in lines)
     assert len(prompts) == 8
     assert {server.bucket_of(len(p)) for p in prompts} == {16, 32}
-    assert server.aot_compiles == len(server._exec) == 7
+    # the decode, its token input, and a prefill for each bucket at 1, 2
+    # and 4 rows
+    assert server.aot_compiles == len(server._exec) == 8
 
     agree = smoke.reintegration_phase(server, prompts, tokens, max_new=8,
                                       log=lines.append)
     assert 0 <= agree <= 64
-    assert server.swap_epochs == 1 and server.aot_compiles == 14
+    assert server.swap_epochs == 1 and server.aot_compiles == 16
     # the install was popped again: the registry is as it was
     assert ops.generation("attention") == gen0
     assert any("tokens equal to the jnp path" in ln for ln in lines)

@@ -1,9 +1,11 @@
 """``BatchedServer``'s profiler spans, read back from a real trace: every
 phase of a step nests inside its ``serve.step`` in order, each executable
 call lies inside the span that names it, and tracing changes no served
-token."""
+token.  The server runs one step ahead: the decode dispatched in step k
+is waited for, and its tokens appended, in step k+1."""
 import glob
 import os
+import re
 import sys
 
 import jax
@@ -139,7 +141,13 @@ def test_every_phase_nests_in_its_step_in_order(traced):
                          "serve.prefill_wait": "w", "serve.decode": "d",
                          "serve.decode_wait": "v",
                          "serve.bookkeeping": "b"}[x[0]] for x in inside)
-        assert order.replace("pw", "") in ("", "dvb", "r", "rdvb"), order
+        # dispatch every prefill and the decode, then wait for the last
+        # decode and this step's prefills, then the bookkeeping
+        m = re.fullmatch("r?(p*)d?(v?)(w?)(b?)", order)
+        assert m, order
+        prefill, wait_decode, wait_prefill, book = m.groups()
+        assert bool(prefill) == bool(wait_prefill), order
+        assert bool(book) == bool(wait_decode or wait_prefill), order
         assert all(a[2] <= b[1] for a, b in zip(inside, inside[1:]))
     # no phase of a step lies outside one
     assert seen == sum(x[0] in IN_STEP | {"serve.rebuild"} for x in events)
@@ -165,10 +173,15 @@ def test_decode_spans_are_the_benchmarks_decode_steps(traced):
                     window_steps=(0, clock.step + 1), compiles_in_window=0,
                     trace=None)
     steps = [e for e in events if e[0] == "serve.step"]
-    for phase in ("serve.decode", "serve.decode_wait", "serve.bookkeeping"):
-        got = [k for k, s in enumerate(steps)
-               if any(x[0] == phase for x in within(events, s))]
-        assert got == sorted(rec.window_decode_steps()) and len(got) > 5
+
+    def having(phase):
+        return [k for k, s in enumerate(steps)
+                if any(x[0] == phase for x in within(events, s))]
+    # the decode of step k has its wait and its tokens in step k+1
+    decoded = sorted(rec.window_decode_steps())
+    assert [k + 1 for k in having("serve.decode")] == decoded
+    assert having("serve.decode_wait") == decoded and len(decoded) > 5
+    assert set(decoded) <= set(having("serve.bookkeeping"))
 
 
 def test_tracing_changes_no_served_token(traced, model_params):
