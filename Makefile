@@ -1,6 +1,5 @@
 .PHONY: verify test-fast test-workers test-conformance test-measure \
-	test-serve test-kernels test-population test-fleet test-chaos bench \
-	bench-full bench-serve
+	test-serve test-kernels test-population bench bench-full
 
 # Tier-1 tests (ROADMAP.md)
 verify:
@@ -12,10 +11,11 @@ test-fast:
 		--ignore=tests/test_core_properties.py
 
 # Worker-fabric suite: subprocess-executor smoke tests, fault paths,
-# cross-process cache dedup (the CI test-workers job)
+# cross-process cache dedup, the spec wire and worker protocol (the CI
+# test-workers job)
 test-workers:
 	REPRO_CAMPAIGN_WORKERS=2 PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} \
-		python -m pytest -q tests/test_workers.py
+		python -m pytest -q tests/test_workers.py tests/test_worker_fabric.py
 
 # Executor behavioral contract (winner equivalence, cache replay, fault
 # paths, cross-process pattern inheritance) + PatternStore journal suite
@@ -53,27 +53,6 @@ test-kernels:
 test-population:
 	REPRO_CAMPAIGN_WORKERS=2 PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} \
 		python -m pytest -q tests/test_population.py
-
-# Networked campaign fleet: RemoteExecutor over the spec wire, per-host
-# lease/namespace resolution, journal replication, and the loopback
-# 2-host e2e legs — spawn transport only, no real SSH (the CI
-# test-fleet job)
-test-fleet:
-	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} \
-		python -m pytest -q tests/test_fleet.py
-
-# Fault-injection suite: scripted FaultPlans (kill / torn reply / stall /
-# corrupt journal), reconnect backoff, quarantine + readmission, and the
-# replication-safe compaction legs — loopback only, no real SSH (the CI
-# test-chaos job)
-test-chaos:
-	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} \
-		python -m pytest -q tests/test_chaos.py
-
-# Old-vs-new serving benchmark (table 9) on the reduced LM
-bench-serve:
-	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} \
-		python -m benchmarks.table9_serving
 
 # Campaign-engine benchmark tables (CI-scale parameters)
 bench:

@@ -49,8 +49,6 @@ class BenchContext:
     max_workers: Optional[int] = None
     executor: Optional[str] = None   # inprocess | subprocess | local-cluster
     measure: Optional[MeasureConfig] = None   # adaptive-engine policy
-    serve_slots: Optional[int] = None         # table 9: KV slot pool size
-    serve_buckets: Optional[List[int]] = None  # table 9: prefill buckets
     # population-search policy (table 11; None → each table's default /
     # the greedy loop elsewhere)
     population: Optional[PopulationConfig] = None

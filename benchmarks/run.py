@@ -11,9 +11,7 @@ and writes the aggregate JSON next to the dry-run results.
 picks the transport (inprocess | subprocess | local-cluster); table 6
 (``--tables 6``) is the worker-fabric demonstration — in-process vs
 subprocess equivalence plus the wall-clock scaling table, written to
-``results/workers_demo.json``.  Table 9 (``--tables 9``) is the
-old-vs-new serving-engine comparison; ``--slots`` / ``--buckets`` size
-its KV slot pool and prefill bucket ladder.
+``results/workers_demo.json``.
 
 ``--full`` (or REPRO_BENCH_FULL=1) uses the paper's parameters
 (D=6/10, N=3/5, R=30, k=3); default CI mode keeps the suite minutes-scale.
@@ -81,12 +79,6 @@ def main() -> None:
                          "(default: engine default, 0.05)")
     ap.add_argument("--no-race", action="store_true",
                     help="keep adaptive reps but disable incumbent racing")
-    ap.add_argument("--slots", type=int, default=None,
-                    help="table 9: serving KV-cache slot pool size "
-                         "(default 4)")
-    ap.add_argument("--buckets", default=None, metavar="N,N,...",
-                    help="table 9: prefill length buckets (default: "
-                         "power-of-two ladder up to max_len)")
     ap.add_argument("--pop-size", type=int, default=None,
                     help="table 11: population size (individuals kept)")
     ap.add_argument("--pop-generations", type=int, default=None,
@@ -106,9 +98,8 @@ def main() -> None:
     from benchmarks import (perf_hillclimb, table1_polybench_a,
                             table2_polybench_b, table3_appsdk,
                             table4_hotspots, table5_serve, table6_workers,
-                            table7_ppi, table8_measure, table9_serving,
-                            table10_diagnosis, table11_population,
-                            table12_fleet, table13_chaos)
+                            table7_ppi, table8_measure,
+                            table10_diagnosis, table11_population)
 
     measure = None
     if args.fixed_r or args.ci_rel is not None or args.no_race:
@@ -128,8 +119,6 @@ def main() -> None:
             per_persona=args.pop_per_persona or base.per_persona,
             migrate=not args.no_migrate)
 
-    serve_buckets = [int(b) for b in args.buckets.split(",")] \
-        if args.buckets else None
     if args.out:
         res_dir = os.path.dirname(args.out) or "."
         os.makedirs(res_dir, exist_ok=True)
@@ -150,17 +139,14 @@ def main() -> None:
             cache=cache,
             db=ResultsDB(os.path.join(res_dir, "campaign.jsonl")),
             max_workers=args.workers, executor=args.executor,
-            measure=measure, serve_slots=args.slots,
-            serve_buckets=serve_buckets, population=population)
+            measure=measure, population=population)
     else:           # --out '': leave no state on disk
         cache = None if args.no_cache else EvalCache()
         store = PatternStore(args.patterns) \
             if args.patterns and args.patterns != "none" else PatternStore()
         ctx = BenchContext(store=store, cache=cache,
                            max_workers=args.workers, executor=args.executor,
-                           measure=measure, serve_slots=args.slots,
-                           serve_buckets=serve_buckets,
-                           population=population)
+                           measure=measure, population=population)
 
     tables = {
         "1": ("table1_polybench_a", table1_polybench_a.main),
@@ -171,11 +157,8 @@ def main() -> None:
         "6": ("table6_workers", table6_workers.main),
         "7": ("table7_ppi", table7_ppi.main),
         "8": ("table8_measure", table8_measure.main),
-        "9": ("table9_serving", table9_serving.main),
         "10": ("table10_diagnosis", table10_diagnosis.main),
         "11": ("table11_population", table11_population.main),
-        "12": ("table12_fleet", table12_fleet.main),
-        "13": ("table13_chaos", table13_chaos.main),
         "hillclimb": ("perf_hillclimb", perf_hillclimb.main),
     }
     table_ids = [t.strip() for t in args.tables.split(",")]

@@ -1,12 +1,9 @@
 """Continuous-batching serving example on a reduced config.
 
-Mixed-length traffic is served three ways:
+Mixed-length traffic is served two ways:
 
 1. fixed-batch ``generate()`` — everything padded into one rectangle,
-2. ``FixedBatchServer`` — the pre-continuous baseline: single shared
-   decode position, one prefill device call per request, every prompt
-   padded to the longest,
-3. ``BatchedServer`` — ragged per-slot decode, bucketed packed prefill,
+2. ``BatchedServer`` — ragged per-slot decode, bucketed packed prefill,
    per-bucket AOT executables built at startup.
 
 The continuous engine's greedy tokens are checked against ``generate()``
@@ -29,7 +26,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
-from repro.serve import BatchedServer, FixedBatchServer, generate
+from repro.serve import BatchedServer, generate
 
 
 def main():
@@ -63,29 +60,15 @@ def main():
     print(f"generate(): {out.shape} in {dt:.2f}s "
           f"({out.size / dt:.1f} tok/s, all prompts padded to {longest})")
 
-    def drive(srv, reqs):
-        t0 = time.time()
-        srv.run(max_steps=2000)
-        dt = time.time() - t0
-        toks = sum(len(r.tokens) for r in reqs)
-        return toks, dt
-
-    # 2. old engine: shared decode position, per-request prefill
-    old = FixedBatchServer(model, params, slots=args.slots,
-                           prompt_len=longest,
-                           max_len=longest + args.max_new + 1)
-    old_reqs = [old.submit(np.pad(p, (0, longest - len(p))),
-                           max_new=args.max_new) for p in prompts]
-    toks, dt = drive(old, old_reqs)
-    print(f"FixedBatchServer: {toks} tokens in {dt:.2f}s "
-          f"({toks / dt:.1f} tok/s, prompts padded to {longest})")
-
-    # 3. continuous engine: ragged decode + bucketed packed prefill
+    # 2. continuous engine: ragged decode + bucketed packed prefill
     srv = BatchedServer(model, params, slots=args.slots, max_len=96)
     print(f"BatchedServer: buckets {srv.buckets}, "
           f"{srv.aot_compiles} AOT executables")
     reqs = [srv.submit(p, max_new=args.max_new) for p in prompts]
-    toks, dt = drive(srv, reqs)
+    t0 = time.time()
+    srv.run(max_steps=2000)
+    dt = time.time() - t0
+    toks = sum(len(r.tokens) for r in reqs)
     print(f"BatchedServer: {toks} tokens in {dt:.2f}s "
           f"({toks / dt:.1f} tok/s, ragged lengths {sorted(set(lens))})")
 

@@ -28,13 +28,9 @@ from repro.core.evalcache import (EvalCache, EvalRecord, ResultsDB,
                                   spec_key, this_host)
 from repro.core.optimizer import (CandidateLog, Evaluator, OptConfig,
                                   OptResult, RoundLog, optimize)
-from repro.core.chaos import ChaosInjector, Fault, FaultPlan
-from repro.core.workers import (CaseJob, Executor, FleetHost,
-                                InProcessExecutor, LocalClusterExecutor,
-                                RemoteExecutor, SubprocessExecutor,
-                                WorkerContext, backoff_schedule,
-                                make_executor, run_case_job)
-from repro.core.replicate import JournalLink, Replicator, drain_endpoint
+from repro.core.workers import (CaseJob, Executor, InProcessExecutor,
+                                LocalClusterExecutor, SubprocessExecutor,
+                                WorkerContext, make_executor, run_case_job)
 from repro.core.campaign import Campaign
 from repro.core import integrate
 from repro.core import extraction

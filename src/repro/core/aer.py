@@ -32,20 +32,15 @@ class RepairRecord:
 class WorkerFault(RuntimeError):
     """Process-level evaluation fault — the AER taxonomy's fourth class,
     beside build/fe/run failures: the *worker* evaluating the MEP died
-    (``kind="crash"``), exceeded its wall-clock budget
-    (``kind="timeout"``), or could not be reached at all
-    (``kind="connect"`` — the fleet transport's bounded connect failed
-    even after the reconnect/backoff schedule).  Unlike the
-    variant-level classes there is no variant to repair; the automatic
-    remedy is worker replacement — the executor respawns the process
-    (or re-establishes the connection) and retries the job on a fresh
-    worker, raising this fault only once the retry budget is spent.
-    Repeated faults against one fleet host additionally feed
-    ``RemoteExecutor``'s quarantine logic."""
+    (``kind="crash"``) or exceeded its wall-clock budget
+    (``kind="timeout"``).  Unlike the variant-level classes there is no
+    variant to repair; the automatic remedy is worker replacement — the
+    executor respawns the process and retries the job on a fresh
+    worker, raising this fault only once the retry budget is spent."""
 
     def __init__(self, kind: str, job: str, detail: str = "",
                  attempts: int = 1):
-        self.kind = kind              # crash | timeout | connect
+        self.kind = kind              # crash | timeout
         self.job = job
         self.detail = detail
         self.attempts = attempts

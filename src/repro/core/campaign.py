@@ -115,17 +115,11 @@ class Campaign:
         # campaign this scheduler process creates (e.g. the autotuner's
         # repeated cycles) shares ONE lease file and ONE registry entry
         # — timing contends for the same CPUs whichever campaign owns it
-        # lease_scope records the derivation coordinates when WE derived
-        # the path (vs caller-pinned): the spec wire form ships them so
-        # fleet workers on other hosts re-resolve the lease against
-        # their own hostname — a lease arbitrates one machine's CPUs
-        self.lease_scope = None
         if lease_path is None and not getattr(platform,
                                               "concurrency_safe", False):
             cache_path = cache.path if cache is not None else None
-            scope = str(os.getpid())
-            lease_path = default_lease_path(cache_path, scope=scope)
-            self.lease_scope = {"cache": cache_path, "scope": scope}
+            lease_path = default_lease_path(cache_path,
+                                            scope=str(os.getpid()))
         self.lease_path = lease_path
         self.verbose = verbose
         if max_workers is None:
@@ -171,22 +165,17 @@ class Campaign:
                             patterns=self.patterns, db=self.db,
                             verbose=self.verbose, measure=self.measure,
                             lease_path=self.lease_path,
-                            lease_scope=self.lease_scope,
                             population=self.population)
         outcomes = self.executor.run(jobs, ctx, campaign_id=campaign_id,
                                      stop=stop)
         failures = [(j, o) for j, o in zip(jobs, outcomes)
                     if isinstance(o, Exception)]
         oks = [o for o in outcomes if isinstance(o, OptResult)]
-        fleet_events = getattr(self.executor, "fleet_events", None)
         if self.db:
             self.db.append(
                 "campaign_end", id=campaign_id,
                 wall_s=round(time.time() - t0, 3),
                 cache=self.cache.stats() if self.cache else None,
-                # fleet fault-tolerance counters (RemoteExecutor only):
-                # reconnects / quarantines / readmissions / reroutes
-                fleet=fleet_events() if callable(fleet_events) else None,
                 # campaign-level PPI health: how many inherited hints
                 # were suggested vs. actually landed in round winners
                 hints_suggested=sum(o.hints_suggested for o in oks),

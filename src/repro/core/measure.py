@@ -98,29 +98,16 @@ def resolve_lease(cfg: Optional[MeasureConfig],
     return cfg
 
 
-def default_lease_path(cache_path: Optional[str], scope: str,
-                       host: Optional[str] = None) -> str:
+def default_lease_path(cache_path: Optional[str], scope: str) -> str:
     """The one rule for where a timing lease lives: next to the shared
     eval cache when there is one (every process sharing the cache shares
     the lease), else a ``scope``-keyed file in the temp dir.  Both the
     campaign scheduler and the bare-executor spec path derive from here
-    so the two can never drift apart.
-
-    The path is **host-scoped** (``host=None`` → ``this_host()``): a
-    timing lease arbitrates contention for ONE machine's CPUs, so when
-    the eval cache is shared across hosts (shared filesystem, or the
-    remote fleet's journal replication) every host must get its *own*
-    arbiter file — serializing host A's wall-clock slices against host
-    B's would throttle the fleet without protecting anything.  Workers
-    re-derive the path with their own hostname from the spec wire form's
-    ``lease_scope`` (see ``workers.job_to_spec``)."""
-    from repro.core.evalcache import this_host
-    host = this_host() if host is None else host
-    tag = f"@{host}" if host else ""
+    so the two can never drift apart."""
     if cache_path:
-        return f"{cache_path}.timelease{tag}"
+        return f"{cache_path}.timelease"
     return os.path.join(tempfile.gettempdir(),
-                        f"repro-timelease-{scope}{tag}.lock")
+                        f"repro-timelease-{scope}.lock")
 
 
 # ---------------------------------------------------------------------------

@@ -155,7 +155,7 @@ def emit_script(mep: MEP, variant: Variant, *,
     in the header as the achieved reps/CI provenance."""
     c = mep.constraints
     # the artifact must run anywhere: the campaign's lease file is not
-    # meaningful outside the process fleet that created it
+    # meaningful outside the processes that created it
     m = dataclasses.replace(measure, lease_path=None) if measure \
         else MeasureConfig()
     achieved = ""

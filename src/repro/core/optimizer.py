@@ -50,12 +50,6 @@ class OptConfig:
     # the greedy one-variant-per-round loop.  The campaign-level default
     # (WorkerContext.population) applies when this is None.
     population: Optional[Any] = None
-    # ppi=False runs the PatternStore record-only: wins are journaled
-    # (and replicate across the fleet) but rounds don't *consume* hints.
-    # Chaos/equivalence harnesses use it to keep winner identity
-    # independent of cross-case hint timing while still exercising the
-    # shared journal machinery.
-    ppi: bool = True
 
     def to_dict(self) -> Dict[str, Any]:
         return asdict(self)            # nested dataclasses → plain dicts

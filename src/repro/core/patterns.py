@@ -57,9 +57,8 @@ import warnings
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
-from repro.core.evalcache import (COMPACT_EV, FileLock, append_jsonl,
-                                  compaction_marker, default_namespace,
-                                  drain_replicas, json_safe)
+from repro.core.evalcache import (LEGACY_MARKER_EV, FileLock, append_jsonl,
+                                  default_namespace, json_safe)
 from repro.core.kernelcase import KernelCase, Variant
 
 
@@ -135,11 +134,6 @@ class PatternStore:
     def __init__(self, path: Optional[str] = None, *,
                  namespace: Optional[str] = None):
         self.path = path
-        # like EvalCache: a host-derived default namespace must be
-        # re-derived by workers on other hosts (the wire form ships
-        # None), so pattern provenance names the host that actually
-        # recorded the win — patterns still cross namespaces freely
-        self.ns_explicit = namespace is not None
         self.namespace = namespace if namespace is not None \
             else default_namespace()
         self._lock = threading.Lock()
@@ -151,7 +145,6 @@ class PatternStore:
         self._offset = 0         # how far into the journal we have read
         self._ino: Optional[int] = None
         self._lines = 0          # journal lines behind the merged view
-        self._epoch = 0          # last compaction epoch seen (monotonic)
         self._dirty = False      # journal holds quarantined (bad) lines
         self.quarantined = 0     # corrupt lines shunted aside, cumulative
         if path and os.path.exists(path):
@@ -167,8 +160,7 @@ class PatternStore:
                 "subprocess executors need a file-backed PatternStore "
                 "(or none): an in-memory store cannot be shared across "
                 "processes")
-        return {"path": self.path,
-                "ns": self.namespace if self.ns_explicit else None}
+        return {"path": self.path, "ns": self.namespace}
 
     @staticmethod
     def from_spec(spec: Dict[str, Any]) -> "PatternStore":
@@ -334,12 +326,8 @@ class PatternStore:
         "hint": one suggested-hint outcome; "acc": a compaction-written
         aggregate (n suggestions, w wins).  Caller holds self._lock."""
         ev = obj["ev"]
-        if ev == COMPACT_EV:
-            # compaction-epoch marker: coordination state for the
-            # replication tails, a no-op for the merged view
-            self._epoch = max(self._epoch,
-                              int(obj.get("epoch", 0) or 0))
-            return
+        if ev == LEGACY_MARKER_EV:
+            return            # an older journal's compaction marker
         key = (json.dumps(obj.get("delta", {}), sort_keys=True,
                           default=str),
                str(obj.get("family", "")), str(obj.get("bottleneck", "")))
@@ -500,8 +488,8 @@ class PatternStore:
 
     def _merged_lines(self) -> int:
         """Lines a compaction would write: one per pattern + one per
-        acceptance-ledger bucket + the epoch marker."""
-        return len(self._merged) + len(self._acc) + 1
+        acceptance-ledger bucket."""
+        return len(self._merged) + len(self._acc)
 
     def _maybe_compact_locked(self) -> None:
         if not self.path or self._lines < self.COMPACT_MIN_LINES:
@@ -511,10 +499,7 @@ class PatternStore:
         self._compact_locked()
 
     def compact(self) -> None:
-        """Force a journal compaction (replication-safe: any live
-        Replicator ending at this journal is drained first, and the
-        rewrite closes with a compaction-epoch marker the tails resync
-        on)."""
+        """Force a journal compaction."""
         with self._lock:
             self._compact_locked()
 
@@ -522,17 +507,14 @@ class PatternStore:
         """Rewrite the journal as one line per merged pattern, under the
         store lock so no concurrent append lands between the tail read
         and the ``os.replace`` (it would be silently dropped).  Caller
-        must NOT hold the store flock: the pre-compaction replica drain
-        appends under it."""
+        must NOT hold the store flock."""
         if not self.path:
             return
         d = os.path.dirname(self.path)
         if d:
             os.makedirs(d, exist_ok=True)
-        drain_replicas(self.path)
         with _StoreLock(self.path):
             self._reload_under_flock_locked()
-            self._epoch += 1
             tmp = f"{self.path}.tmp{os.getpid()}"
             with open(tmp, "w") as f:
                 for p in self._merged.values():
@@ -543,8 +525,6 @@ class PatternStore:
                         {"ev": "acc", "delta": json.loads(dk),
                          "family": fam, "bottleneck": bn,
                          "n": n, "w": w}), default=str) + "\n")
-                f.write(json.dumps(compaction_marker(self._epoch),
-                                   default=str) + "\n")
             os.replace(tmp, self.path)
             st = os.stat(self.path)
             self._offset, self._ino = st.st_size, st.st_ino

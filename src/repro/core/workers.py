@@ -1,4 +1,4 @@
-"""Out-of-process evaluation fabric: transport-agnostic campaign workers.
+"""Out-of-process evaluation fabric: campaign workers on one host.
 
 The paper's premise is that MEPs make kernel evaluation cheap and
 independent of the full application; this module makes it independent of
@@ -16,23 +16,10 @@ the *scheduler's process* too.  A campaign hands its ``CaseJob``s to an
   flock; workers record wins round-by-round and re-read hints at round
   boundaries, so §3.2 Performance Pattern Inheritance flows *across*
   worker processes mid-campaign), and the ``ResultsDB`` journal (atomic
-  O_APPEND lines) are the only shared state, so the same code path
-  scales to remote hosts over shared storage.
+  O_APPEND lines) are the only shared state.
 * ``LocalClusterExecutor`` — multiplexes N persistent subprocess
   workers.  Workers persist across campaigns, amortizing spawn cost for
-  the serving autotuner's repeated cycles.  Its slot router is
-  **affinity-aware**: jobs on the same case prefer the worker that
-  already served that case (it holds the warm jit caches and MEP state),
-  falling back to work-stealing so no slot idles.
-* ``RemoteExecutor``        — the same eval-spec protocol over the
-  network: per-host worker slots speaking line-JSON over TCP sockets
-  (``scripts/remote_worker.py`` servers), an SSH-command transport that
-  reuses ``_WorkerProc`` with a remote spawn command (ssh pipes stdio),
-  and a ``spawn`` transport that launches loopback servers for
-  simulated fleets/CI.  Slot routing is host-affinity-aware; lease
-  paths and cache namespaces resolve *per host* from the spec wire
-  form; journals are shared via a common filesystem or the
-  ``repro.core.replicate`` tail-ship loop (over the same wire).
+  the serving autotuner's repeated cycles.
 
 Measured (wall-clock) platforms fan out across workers like analytic
 ones: every spec carries the campaign's **timing lease** (an flock'd
@@ -41,21 +28,11 @@ wall-clock slices serialize on it — build/compile/FE/LLM work overlaps
 freely — so eq. 3's trimmed mean stays clean without the one-exclusive-
 worker pinning this executor used to apply.
 
-Process-level crashes, timeouts, and connection failures are folded
-into the AER taxonomy as ``WorkerFault`` (kind crash|timeout|connect)
-with automatic worker replacement: the dead worker is respawned (a
-broken connection re-established under deterministic exponential
-backoff) and the job retried on the fresh process; only a job that
-exhausts its retry budget surfaces the fault, which the campaign
-records like any other job failure.  ``RemoteExecutor`` additionally
-tracks per-host health: a host whose slots keep faulting is
-**quarantined** (its claims released so in-flight cases re-route to
-healthy hosts) and probed with protocol pings under backoff until it
-answers again — a campaign completes degraded rather than stalling.
-All transitions (``host_quarantined`` / ``host_readmitted`` /
-``job_rerouted``) are journaled into the ResultsDB, and the scripted
-fault-injection harness in ``repro.core.chaos`` drives every one of
-these paths deterministically under test.
+Process-level crashes and timeouts are folded into the AER taxonomy as
+``WorkerFault`` (kind crash|timeout) with automatic worker replacement:
+the dead worker is respawned and the job retried on the fresh process;
+only a job that exhausts its retry budget surfaces the fault, which the
+campaign records like any other job failure.
 
 The LLM proposer's round prompts are coalesced across the concurrent
 cases of an in-process campaign through a shared ``LLMBatcher`` (one
@@ -64,23 +41,19 @@ their own process only.
 """
 from __future__ import annotations
 
-import atexit
 import json
 import os
+import queue
 import select
-import shlex
-import socket
 import subprocess
 import sys
 import tempfile
 import threading
 import time
-import weakref
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple, Union
+from typing import Any, Dict, List, Optional, Tuple
 
 from repro.core.aer import AER, WorkerFault
-from repro.core.chaos import ChaosInjector, FaultPlan
 from repro.core.diagnosis import diagnose_feedback
 from repro.core.evalcache import EvalCache, ResultsDB, json_safe, this_host
 from repro.core.kernelcase import KernelCase
@@ -130,11 +103,6 @@ class WorkerContext:
     # worker timing this campaign's wall-clock sections
     measure: Optional[MeasureConfig] = None
     lease_path: Optional[str] = None
-    # when lease_path was *derived* (not caller-pinned), the derivation
-    # coordinates ({"cache": ..., "scope": ...}) travel in the spec so a
-    # worker on another host re-resolves the lease with its own
-    # hostname — a lease arbitrates ONE machine's CPUs, never a fleet's
-    lease_scope: Optional[Dict[str, Any]] = None
     # campaign-level default population-search policy (per-job
     # cfg.population wins); None → the greedy §3.2 loop
     population: Optional[PopulationConfig] = None
@@ -274,7 +242,7 @@ def _greedy_rounds(job: CaseJob, platform: Platform, res: OptResult,
         diag = diagnose_feedback(feedback, ci_rel=best_ci_rel)
         last_bottleneck = diag.bottleneck
         hints: Optional[List[Pattern]] = None
-        if patterns is not None and getattr(cfg, "ppi", True):
+        if patterns is not None:
             # round boundary: fold other workers' journal appends in, so
             # a win recorded by a concurrent case — possibly in another
             # process — reaches this round's proposal wave (§3.2 PPI).
@@ -397,21 +365,15 @@ def job_to_spec(job: CaseJob, ctx: WorkerContext, campaign_id: str
             "subprocess executors need a file-backed EvalCache (or none): "
             "an in-memory cache cannot be shared across processes")
     # cross-process timing lease: every worker timing this campaign's
-    # wall-clock sections ON THE SAME HOST must serialize on the same
-    # arbiter file.  The campaign provides one (next to its cache,
-    # host-scoped); for direct executor users the same rule is
-    # re-derived here, campaign-scoped — a measured platform must never
-    # fan out lease-less.  ``lease_scope`` ships the derivation
-    # coordinates so a worker on ANOTHER host re-resolves the lease with
-    # its own hostname instead of contending with (or, worse, silently
-    # sharing eq. 3 slices with) the scheduler's host.
+    # wall-clock sections must serialize on the same arbiter file.  The
+    # campaign provides one (next to its cache); for direct executor
+    # users the same rule is re-derived here, campaign-scoped — a
+    # measured platform must never fan out lease-less.
     lease = ctx.lease_path
-    lease_scope = ctx.lease_scope
     if lease is None and not getattr(ctx.platform, "concurrency_safe",
                                      False):
         cache_path = ctx.cache.path if ctx.cache is not None else None
         lease = default_lease_path(cache_path, scope=campaign_id)
-        lease_scope = {"cache": cache_path, "scope": campaign_id}
     return {
         "job": {
             "case": job.case.to_dict(),
@@ -425,14 +387,12 @@ def job_to_spec(job: CaseJob, ctx: WorkerContext, campaign_id: str
             "scale": job.mep.scale if job.mep else None,
         },
         "platform": ctx.platform.name,
-        # a host-derived (default) namespace ships as None: the worker
-        # re-derives it locally, so measured records taken on host B are
-        # stamped host B and never replay as if timed on host A.  Only a
-        # caller-pinned namespace crosses the wire verbatim.
+        # the namespace ships as the scheduler holds it: workers stamp
+        # their measured records with it, so they replay in the
+        # scheduler's view
         "cache": None if ctx.cache is None else {
             "path": ctx.cache.path,
-            "ns": ctx.cache.namespace
-            if getattr(ctx.cache, "ns_explicit", True) else None,
+            "ns": ctx.cache.namespace,
             "ttl_s": ctx.cache.ttl_s},
         # a file-backed PatternStore ships its coordinates so workers
         # record and suggest against the shared journal; an in-memory
@@ -444,8 +404,6 @@ def job_to_spec(job: CaseJob, ctx: WorkerContext, campaign_id: str
         "population": ctx.population.to_dict()
         if ctx.population else None,
         "lease": lease,
-        "lease_scope": lease_scope,
-        "host": this_host(),
         "campaign": campaign_id,
         "verbose": ctx.verbose,
         "stop": False,
@@ -468,20 +426,9 @@ def job_from_spec(spec: Dict[str, Any]) -> Tuple[CaseJob, Optional[int]]:
 
 
 def lease_for_spec(spec: Dict[str, Any]) -> Optional[str]:
-    """The timing-lease path THIS host must use for ``spec``.  A lease
-    arbitrates contention for one machine's CPUs: when the spec was
-    built on another host (``spec["host"]``) and its lease path was
-    *derived* (``lease_scope`` present) rather than caller-pinned, the
-    worker re-derives it with its own hostname — sharing host A's
-    arbiter file from host B would serialize the fleet's wall-clock
-    slices against each other without protecting anything."""
-    lease = spec.get("lease")
-    scope = spec.get("lease_scope")
-    if scope is not None and spec.get("host") \
-            and spec["host"] != this_host():
-        return default_lease_path(scope.get("cache"),
-                                  scope=str(scope.get("scope") or ""))
-    return lease
+    """The timing-lease path a worker uses for ``spec``: the one the
+    scheduler shipped."""
+    return spec["lease"]
 
 
 # ---------------------------------------------------------------------------
@@ -587,26 +534,38 @@ class InProcessExecutor(Executor):
 
 
 # ---------------------------------------------------------------------------
-class _LineChannel:
-    """One endpoint of the line-JSON spec protocol over a byte stream.
-    The buffer holds raw *bytes*; a line is decoded only once its
-    terminating newline has arrived, so a multi-byte UTF-8 sequence
-    split across read chunks can never be torn (decoding chunk
+class _WorkerProc:
+    """One worker subprocess + its line-JSON spec protocol.  stderr goes
+    to a temp file whose tail becomes the fault diagnostic on crash.
+
+    The stdio pipes are opened in *binary* mode and ``recv`` reads the
+    raw fd: a ``text=True`` TextIOWrapper sitting on the same fd could
+    strand bytes in its own buffer where the fd-level reader would never
+    see them.  The receive buffer holds raw *bytes*; a line is decoded
+    only once its terminating newline has arrived, so a multi-byte UTF-8
+    sequence split across read chunks can never be torn (decoding chunk
     boundaries with ``errors="replace"`` used to corrupt it)."""
 
-    _buf: bytes = b""
+    def __init__(self, cmd: List[str], env: Dict[str, str], slot: Any):
+        self.slot = slot
+        self._buf = b""
+        self.log = tempfile.NamedTemporaryFile(
+            mode="w+b", prefix=f"repro-worker{slot}-", suffix=".log",
+            delete=False)
+        self.proc = subprocess.Popen(
+            cmd, env=env, stdin=subprocess.PIPE, stdout=subprocess.PIPE,
+            stderr=self.log)
 
-    # transport hooks ---------------------------------------------------
     def _fd(self) -> int:
-        raise NotImplementedError
+        return self.proc.stdout.fileno()
 
     def alive(self) -> bool:
-        raise NotImplementedError
+        return self.proc.poll() is None
 
-    def diagnostic(self) -> str:
-        return "peer closed"
+    def send(self, spec: Dict[str, Any]) -> None:
+        self.proc.stdin.write((json.dumps(spec) + "\n").encode())
+        self.proc.stdin.flush()
 
-    # ------------------------------------------------------------------
     def recv(self, timeout_s: Optional[float]) -> Dict[str, Any]:
         """Read one protocol line; raises TimeoutError / EOFError."""
         deadline = None if timeout_s is None else \
@@ -633,35 +592,6 @@ class _LineChannel:
             if not chunk:
                 raise EOFError(self.diagnostic())
             self._buf += chunk
-
-
-class _WorkerProc(_LineChannel):
-    """One worker subprocess + its pipe protocol.  stderr goes to a temp
-    file whose tail becomes the fault diagnostic on crash.  The stdio
-    pipes are opened in *binary* mode: ``recv`` reads the raw fd (via
-    ``_LineChannel``), and a ``text=True`` TextIOWrapper sitting on the
-    same fd could strand bytes in its own buffer where the fd-level
-    reader would never see them."""
-
-    def __init__(self, cmd: List[str], env: Dict[str, str], slot: Any):
-        self.slot = slot
-        self._buf = b""
-        self.log = tempfile.NamedTemporaryFile(
-            mode="w+b", prefix=f"repro-worker{slot}-", suffix=".log",
-            delete=False)
-        self.proc = subprocess.Popen(
-            cmd, env=env, stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-            stderr=self.log)
-
-    def _fd(self) -> int:
-        return self.proc.stdout.fileno()
-
-    def alive(self) -> bool:
-        return self.proc.poll() is None
-
-    def send(self, spec: Dict[str, Any]) -> None:
-        self.proc.stdin.write((json.dumps(spec) + "\n").encode())
-        self.proc.stdin.flush()
 
     def diagnostic(self) -> str:
         code = self.proc.poll()
@@ -693,69 +623,6 @@ class _WorkerProc(_LineChannel):
             pass
 
 
-class _ConnectError(OSError):
-    """Connection *establishment* failed (server down, refused, or the
-    bounded connect timeout elapsed) — distinct from a crash of a live
-    worker, so it surfaces as ``WorkerFault(kind="connect")``."""
-
-
-def backoff_schedule(base_s: float, max_s: float,
-                     attempts: int) -> List[float]:
-    """Deterministic (jitter-free) exponential backoff delays:
-    ``base, 2*base, 4*base, ...`` capped at ``max_s``.  Jitter-free on
-    purpose — the chaos harness asserts reconnect timing, and a single
-    scheduler reconnecting to its own fleet has no thundering herd to
-    spread."""
-    return [min(base_s * (2 ** i), max_s) for i in range(max(0, attempts))]
-
-
-class _SocketWorker(_LineChannel):
-    """Scheduler-side handle for one remote worker slot: the exact spec
-    protocol ``_WorkerProc`` speaks over pipes, over a TCP connection to
-    a ``scripts/remote_worker.py`` server.  One connection per slot —
-    the server serves each connection in its own thread, so a host's
-    slots evaluate concurrently."""
-
-    def __init__(self, address: str, slot: Any, *,
-                 connect_timeout_s: float = 30.0):
-        self.slot = slot
-        self.address = address
-        self._buf = b""
-        host, port = address.rsplit(":", 1)
-        try:
-            # bounded: a standing server that is down must fail fast as
-            # a connect fault, not block dispatch for the OS TCP timeout
-            self.sock = socket.create_connection((host, int(port)),
-                                                 timeout=connect_timeout_s)
-        except OSError as e:
-            raise _ConnectError(f"connect {address}: {e}") from e
-        self.sock.setblocking(True)
-        self._closed = False
-
-    def _fd(self) -> int:
-        return self.sock.fileno()
-
-    def alive(self) -> bool:
-        return not self._closed
-
-    def send(self, spec: Dict[str, Any]) -> None:
-        self.sock.sendall((json.dumps(spec) + "\n").encode())
-
-    def diagnostic(self) -> str:
-        return f"remote worker {self.address} closed the connection"
-
-    def kill(self) -> None:
-        self._closed = True
-        try:
-            self.sock.shutdown(socket.SHUT_RDWR)
-        except OSError:
-            pass
-        try:
-            self.sock.close()
-        except OSError:
-            pass
-
-
 def _worker_cmd() -> List[str]:
     """Spawn command for scripts/worker_main.py, falling back to an
     inline import when the repo layout isn't present (installed use)."""
@@ -781,86 +648,9 @@ def _worker_env() -> Dict[str, str]:
     return env
 
 
-class _AffinityRouter:
-    """Case→host affinity work router for the multi-slot executors.
-
-    Consumers call ``get(host)``; the router prefers (1) a queued job
-    whose case this host already claimed — whoever evaluated a case's
-    first job holds the warm MEP build and jit/eval caches — then (2) a
-    job on an unclaimed case (claiming it for this host), then (3)
-    stealing any queued job so no slot idles while work remains.  A
-    steal does *not* reassign the claim: the original host keeps its
-    warmth for later jobs on the case.  ``get(None)`` is plain FIFO
-    (single-host executors).  ``close()`` wakes all consumers with
-    ``None``."""
-
-    def __init__(self) -> None:
-        self._cv = threading.Condition()
-        self._pending: List[Tuple] = []     # (idx, job, spec, attempt)
-        self._claims: Dict[str, Any] = {}   # case name → claiming host
-        self._closed = False
-
-    def put(self, item: Tuple) -> None:
-        with self._cv:
-            self._pending.append(item)
-            self._cv.notify_all()
-
-    def claim_of(self, case: str) -> Any:
-        with self._cv:
-            return self._claims.get(case)
-
-    @property
-    def closed(self) -> bool:
-        with self._cv:
-            return self._closed
-
-    def release_host(self, host: Any) -> List[str]:
-        """Drop every case→host claim ``host`` holds (quarantine path):
-        the next host to pull a job on those cases claims them fresh —
-        affinity warmth is worthless on a host that stopped answering.
-        Returns the released case names."""
-        with self._cv:
-            released = [c for c, h in self._claims.items() if h == host]
-            for c in released:
-                del self._claims[c]
-            self._cv.notify_all()
-            return released
-
-    def get(self, host: Any) -> Optional[Tuple]:
-        with self._cv:
-            while True:
-                if self._pending:
-                    pick = None
-                    if host is not None:
-                        unclaimed = None
-                        for it in self._pending:
-                            owner = self._claims.get(it[1].case.name)
-                            if owner == host:
-                                pick = it
-                                break
-                            if unclaimed is None and owner is None:
-                                unclaimed = it
-                        if pick is None:
-                            pick = unclaimed   # may still be None → steal
-                    if pick is None:
-                        pick = self._pending[0]
-                    self._pending.remove(pick)
-                    if host is not None:
-                        self._claims.setdefault(pick[1].case.name, host)
-                    return pick
-                if self._closed:
-                    return None
-                self._cv.wait(timeout=0.5)
-
-    def close(self) -> None:
-        with self._cv:
-            self._closed = True
-            self._cv.notify_all()
-
-
 class SubprocessExecutor(Executor):
     """One MEP per worker process: N workers each pull serialized eval
-    specs off a work router, evaluate them in their own interpreter
+    specs off a FIFO work queue, evaluate them in their own interpreter
     (their own GIL, their own jit caches), and ship ``OptResult`` wire
     dicts back.  Crashes and timeouts become ``WorkerFault``s with
     automatic worker replacement; the cache/journal files are the only
@@ -868,11 +658,9 @@ class SubprocessExecutor(Executor):
 
     name = "subprocess"
     persistent = False        # workers live for one run() call
-    affinity = False          # enable case→host routing (_slot_host)
 
     def __init__(self, workers: Optional[int] = None, *,
-                 timeout_s: Optional[float] = None, retries: int = 1,
-                 chaos: Optional[FaultPlan] = None):
+                 timeout_s: Optional[float] = None, retries: int = 1):
         if workers is None:
             workers = int(os.environ.get(
                 "REPRO_CAMPAIGN_WORKERS", str(os.cpu_count() or 2)))
@@ -882,9 +670,6 @@ class SubprocessExecutor(Executor):
             timeout_s = float(env) if env else None
         self.timeout_s = timeout_s
         self.retries = max(0, retries)
-        # scripted fault plan shipped to spawned workers/servers via the
-        # REPRO_CHAOS env var (repro.core.chaos) — None in production
-        self.chaos = chaos
         from collections import deque
         self.dispatch_log = deque(maxlen=4096)          # (job, slot)
         self._procs: Dict[Any, _WorkerProc] = {}        # slot → process
@@ -905,18 +690,6 @@ class SubprocessExecutor(Executor):
         with self._lock:
             return self._slot_locks.setdefault(slot, threading.Lock())
 
-    def _slot_host(self, slot: Any) -> Any:
-        """Affinity unit for the router.  Each local worker process has
-        its own jit/eval caches, so locally the *slot* is the unit;
-        RemoteExecutor maps slots to their host label instead."""
-        return slot
-
-    def _spec_for_slot(self, spec: Dict[str, Any],
-                       slot: Any) -> Dict[str, Any]:
-        """Per-slot spec rewriting hook (RemoteExecutor remaps journal
-        paths for hosts that don't share the scheduler's filesystem)."""
-        return spec
-
     def _inject(self, job: CaseJob, spec: Dict[str, Any]) -> None:
         """Test-only fault injection hook: jobs may carry an ``inject``
         attribute (set by tests) that the worker honors before
@@ -924,30 +697,6 @@ class SubprocessExecutor(Executor):
         inject = getattr(job, "inject", None)
         if inject:
             spec["inject"] = inject
-
-    # -- fault-tolerance hooks (RemoteExecutor overrides) --------------
-    def _slot_gate(self, slot: Any, router: "_AffinityRouter",
-                   ctx: WorkerContext, campaign_id: str) -> bool:
-        """Health gate a slot passes before pulling work.  Returning
-        False makes the slot loop come around again without dequeuing
-        (the gate is responsible for pacing — sleep/probe inside);
-        RemoteExecutor holds quarantined hosts here and probes them
-        back to health.  The local fabric has no per-slot health."""
-        return True
-
-    def _note_ok(self, slot: Any) -> None:
-        """A dispatch on ``slot`` completed a protocol exchange."""
-
-    def _note_fault(self, slot: Any, job: CaseJob, kind: str,
-                    router: "_AffinityRouter", ctx: WorkerContext,
-                    campaign_id: str) -> None:
-        """A dispatch on ``slot`` faulted (called before the retry is
-        re-queued, so a quarantining override releases the host's
-        claims first and the retry lands on a healthy host)."""
-
-    def _note_dispatch(self, slot: Any, job: CaseJob, ctx: WorkerContext,
-                       campaign_id: str) -> None:
-        """``job`` is about to be dispatched on ``slot``."""
 
     def run(self, jobs, ctx, *, campaign_id="", stop=None):
         # serialize everything first: a non-wire-safe job must fail the
@@ -962,9 +711,12 @@ class SubprocessExecutor(Executor):
             return []
         outcomes: List[Any] = [None] * len(jobs)
         slots = self._slots_for(ctx, len(jobs))
-        router = _AffinityRouter()
+        # FIFO work queue of (idx, job, spec, attempt); a retry goes to
+        # the back.  One None per slot closes it once every job has its
+        # outcome.
+        work: "queue.Queue[Optional[Tuple]]" = queue.Queue()
         for i, (job, spec) in enumerate(zip(jobs, specs)):
-            router.put((i, job, spec, 0))
+            work.put((i, job, spec, 0))
         remaining = [len(jobs)]
 
         def finish(idx: int, outcome: Any) -> None:
@@ -972,7 +724,8 @@ class SubprocessExecutor(Executor):
             with self._lock:
                 remaining[0] -= 1
                 if remaining[0] == 0:
-                    router.close()
+                    for _ in slots:
+                        work.put(None)
 
         def fault(idx, job, spec, attempt, kind, detail, slot):
             """AER worker-fault handling: journal, replace the worker,
@@ -986,7 +739,7 @@ class SubprocessExecutor(Executor):
                 except OSError:
                     pass     # a full disk must not turn a retry into a hang
             if attempt < self.retries:
-                router.put((idx, job, spec, attempt + 1))
+                work.put((idx, job, spec, attempt + 1))
             else:
                 finish(idx, WorkerFault(kind, job.name, str(detail)[:500],
                                         attempts=attempt + 1))
@@ -994,33 +747,20 @@ class SubprocessExecutor(Executor):
         def dispatch(slot, idx, job, spec, attempt) -> None:
             if stop is not None and stop.is_set():
                 spec = dict(spec, stop=True)
-            spec = self._spec_for_slot(spec, slot)
             self.dispatch_log.append((job.name, slot))
-            self._note_dispatch(slot, job, ctx, campaign_id)
             try:
                 with self._slot_lock(slot):
-                    worker = self._ensure_worker(slot, ctx)
+                    worker = self._ensure_worker(slot)
                     worker.send(spec)
                     reply = worker.recv(self.timeout_s)
-            except TimeoutError as e:
+            except (EOFError, OSError, ValueError) as e:
+                # TimeoutError is an OSError: a stalled worker is
+                # replaced like a dead one
                 self._replace_worker(slot)
-                self._note_fault(slot, job, "timeout", router, ctx,
-                                 campaign_id)
-                fault(idx, job, spec, attempt, "timeout", e, slot)
+                kind = "timeout" if isinstance(e, TimeoutError) \
+                    else "crash"
+                fault(idx, job, spec, attempt, kind, e, slot)
                 return
-            except _ConnectError as e:
-                self._replace_worker(slot)
-                self._note_fault(slot, job, "connect", router, ctx,
-                                 campaign_id)
-                fault(idx, job, spec, attempt, "connect", e, slot)
-                return
-            except (EOFError, OSError, BrokenPipeError, ValueError) as e:
-                self._replace_worker(slot)
-                self._note_fault(slot, job, "crash", router, ctx,
-                                 campaign_id)
-                fault(idx, job, spec, attempt, "crash", e, slot)
-                return
-            self._note_ok(slot)
             if reply.get("ok"):
                 res = OptResult.from_dict(reply["result"])
                 if ctx.patterns is not None and not ctx.patterns.path:
@@ -1038,11 +778,8 @@ class SubprocessExecutor(Executor):
                     f"{reply.get('error', 'worker error')}"))
 
         def slot_loop(slot: Any) -> None:
-            host = self._slot_host(slot) if self.affinity else None
             while True:
-                if not self._slot_gate(slot, router, ctx, campaign_id):
-                    continue         # gate paces (sleeps/probes) itself
-                item = router.get(host)
+                item = work.get()
                 if item is None:
                     return
                 idx, job, spec, attempt = item
@@ -1089,32 +826,28 @@ class SubprocessExecutor(Executor):
             for attempt in range(self.retries + 1):
                 try:
                     with self._slot_lock(slot):
-                        w = self._ensure_worker(slot, None)
+                        w = self._ensure_worker(slot)
                         w.send({"ping": True})
                         w.recv(timeout_s)
                     last = None
                     break
-                except (TimeoutError, EOFError, OSError,
-                        BrokenPipeError, ValueError) as e:
+                except (EOFError, OSError, ValueError) as e:
                     last = e
                     self._replace_worker(slot)
             if last is not None:
                 kind = "timeout" if isinstance(last, TimeoutError) \
-                    else ("connect" if isinstance(last, _ConnectError)
-                          else "crash")
+                    else "crash"
                 raise WorkerFault(kind, f"warm:{slot}", str(last)[:500],
                                   attempts=self.retries + 1)
 
     # ------------------------------------------------------------------
-    def _ensure_worker(self, slot: int, ctx: Optional[WorkerContext]
-                       ) -> _WorkerProc:
+    def _ensure_worker(self, slot: int) -> _WorkerProc:
         with self._lock:
             w = self._procs.get(slot)
             if w is None or not w.alive():
-                env = _worker_env()
-                if self.chaos is not None:
-                    self.chaos.to_env(env)
-                w = _WorkerProc(_worker_cmd(), env, slot)
+                if w is not None:
+                    w.kill()        # reap a worker that died idle
+                w = _WorkerProc(_worker_cmd(), _worker_env(), slot)
                 self._procs[slot] = w
             return w
 
@@ -1144,538 +877,16 @@ class LocalClusterExecutor(SubprocessExecutor):
     platforms fan out across the whole pool — the pinned exclusive slot
     they used to get is gone; the cross-process timing lease serializes
     only the wall-clock slices while build/compile/FE/LLM work overlaps
-    freely.  Slot routing is affinity-aware: jobs on a case prefer the
-    worker process that already served that case (warm jit/eval caches),
-    with work-stealing as the fallback."""
+    freely."""
 
     name = "local-cluster"
     persistent = True
-    affinity = True
-
-
-# ---------------------------------------------------------------------------
-# networked fleet
-# ---------------------------------------------------------------------------
-@dataclass
-class FleetHost:
-    """One machine in a campaign fleet.  The configured ``name`` IS the
-    host's fleet-wide identity: it ships to the worker as
-    ``REPRO_HOST_ALIAS``, so the measured-cache namespace, the timing
-    lease, and every journal's ``host`` provenance key on it (stable
-    across DHCP renames, and distinct for simulated loopback hosts).
-
-    Transports:
-
-    * ``spawn``  — the executor launches ``scripts/remote_worker.py`` as
-      a local loopback server and connects over TCP: a *simulated* fleet
-      host for CI/benchmarks that exercises the exact socket + per-host
-      namespace/lease code paths of a real one.
-    * ``socket`` — connect to an already-running
-      ``scripts/remote_worker.py`` at ``address`` (``"host:port"``).
-    * ``ssh``    — spawn the stdio worker on the remote machine through
-      ``ssh`` (reusing ``_WorkerProc``: ssh pipes stdio across the
-      wire); ``ssh`` is the target (``user@host``), ``python`` the
-      remote interpreter, ``workdir`` an optional remote repo checkout
-      to run from (its ``src/`` is put on PYTHONPATH).
-
-    ``slots`` is how many jobs the host evaluates concurrently (one
-    socket connection / ssh pipe per slot).  ``cache_path`` /
-    ``patterns_path`` / ``db_path`` remap the spec's journal paths for
-    hosts that do NOT share the scheduler's filesystem; the executor's
-    ``repro.core.replicate`` loop then tail-ships appends both ways
-    (unset → shared filesystem, no rewriting)."""
-    name: str
-    transport: str = "spawn"          # spawn | socket | ssh
-    address: str = ""                 # socket: "host:port"
-    ssh: str = ""                     # ssh: "user@host"
-    python: str = ""                  # ssh: remote interpreter
-    workdir: str = ""                 # ssh: remote repo checkout
-    slots: int = 1
-    cache_path: str = ""
-    patterns_path: str = ""
-    db_path: str = ""
-    # bounded TCP connect for socket/spawn transports: a standing server
-    # that is down fails fast as WorkerFault(kind="connect") instead of
-    # blocking dispatch for the OS TCP timeout
-    connect_timeout_s: float = 10.0
-
-    @staticmethod
-    def from_dict(d: Union[str, Dict[str, Any]]) -> "FleetHost":
-        if isinstance(d, str):
-            return FleetHost(name=d)          # shorthand: spawn, 1 slot
-        return FleetHost(**d)
-
-
-def _remote_worker_cmd() -> List[str]:
-    here = os.path.dirname(os.path.abspath(__file__))
-    script = os.path.abspath(os.path.join(here, "..", "..", "..",
-                                          "scripts", "remote_worker.py"))
-    if not os.path.exists(script):
-        raise FileNotFoundError(
-            f"scripts/remote_worker.py not found at {script} — the spawn "
-            f"transport needs the repo layout")
-    return [sys.executable, "-u", script]
-
-
-def _ssh_worker_cmd(host: "FleetHost") -> List[str]:
-    """ssh command whose stdio IS the worker pipe: `_WorkerProc` with a
-    remote spawn command.  BatchMode keeps a missing key from hanging
-    the fabric on a password prompt."""
-    py = host.python or "python3"
-    inner = (f"{py} -u -c "
-             + shlex.quote("from repro.core.workers import worker_main; "
-                           "raise SystemExit(worker_main())"))
-    env = f"REPRO_HOST_ALIAS={shlex.quote(host.name)}"
-    if host.workdir:
-        wd = shlex.quote(host.workdir)
-        remote = (f"cd {wd} && env {env} "
-                  f"PYTHONPATH={wd}/src:\"$PYTHONPATH\" {inner}")
-    else:
-        remote = f"env {env} {inner}"
-    return ["ssh", "-o", "BatchMode=yes", host.ssh, remote]
-
-
-class _ServerProc:
-    """A spawned loopback ``remote_worker.py`` server: one per spawn
-    host, shared by all that host's slots.  stderr goes to a temp log
-    (jax chatter + diagnostics); the bound port is read from the
-    ``READY <port>`` stdout line."""
-
-    def __init__(self, host: "FleetHost", timeout_s: float = 120.0,
-                 chaos: Optional[FaultPlan] = None):
-        self.host = host
-        self.log = tempfile.NamedTemporaryFile(
-            mode="w+b", prefix=f"repro-fleet-{host.name}-", suffix=".log",
-            delete=False)
-        env = _worker_env()
-        env["REPRO_HOST_ALIAS"] = host.name
-        if chaos is not None:
-            chaos.to_env(env)
-        self.proc = subprocess.Popen(
-            _remote_worker_cmd() + ["--port", "0", "--alias", host.name],
-            env=env, stdin=subprocess.DEVNULL, stdout=subprocess.PIPE,
-            stderr=self.log)
-        self.port = self._read_ready(timeout_s)
-
-    def _read_ready(self, timeout_s: float) -> int:
-        deadline = time.monotonic() + timeout_s
-        fd = self.proc.stdout.fileno()
-        buf = b""
-        while True:
-            nl = buf.find(b"\n")
-            if nl >= 0:
-                line, buf = buf[:nl], buf[nl + 1:]
-                if line.startswith(b"READY "):
-                    return int(line.split()[1])
-                continue          # jax may chat on stdout before READY
-            wait = deadline - time.monotonic()
-            if wait <= 0:
-                self.kill()
-                raise TimeoutError(
-                    f"fleet host {self.host.name}: server not READY "
-                    f"within {timeout_s}s")
-            ready, _, _ = select.select([fd], [], [], min(wait, 1.0))
-            if not ready:
-                if self.proc.poll() is not None:
-                    raise EOFError(
-                        f"fleet host {self.host.name}: server exited "
-                        f"{self.proc.poll()} before READY")
-                continue
-            chunk = os.read(fd, 65536)
-            if not chunk:
-                raise EOFError(
-                    f"fleet host {self.host.name}: server closed stdout "
-                    f"before READY (exit={self.proc.poll()})")
-            buf += chunk
-
-    def alive(self) -> bool:
-        return self.proc.poll() is None
-
-    def kill(self) -> None:
-        try:
-            if self.alive():
-                self.proc.terminate()
-            self.proc.wait(timeout=5)
-        except (OSError, subprocess.TimeoutExpired):
-            try:
-                self.proc.kill()
-            except OSError:
-                pass
-        for h in (self.proc.stdout, self.log):
-            try:
-                h.close()
-            except OSError:
-                pass
-        try:
-            os.unlink(self.log.name)
-        except OSError:
-            pass
-
-
-class RemoteExecutor(SubprocessExecutor):
-    """The eval-spec protocol over the network: one campaign saturating
-    N hosts.  Slots are ``(host, i)`` pairs; each slot speaks the exact
-    line-JSON protocol ``_WorkerProc`` uses over pipes — over a TCP
-    connection to a ``scripts/remote_worker.py`` server (``socket`` /
-    ``spawn`` transports) or over an ssh-piped stdio worker (``ssh``).
-
-    Per-host resolution happens in the spec wire form, not here: a
-    host-derived cache/pattern namespace ships as None and is re-derived
-    worker-side under the host's ``REPRO_HOST_ALIAS`` (so measured
-    records carry the host that timed them and never replay elsewhere),
-    and a derived lease path is re-derived per host from ``lease_scope``
-    (a lease arbitrates ONE machine's CPUs).  Journals are shared via a
-    common filesystem, or — for hosts with ``cache_path`` /
-    ``patterns_path`` / ``db_path`` remaps — by the
-    ``repro.core.replicate`` tail-ship loop, which pumps O_APPEND lines
-    both ways between the scheduler's journals and each host's (both
-    stores merge on replay, so replication is just tail-ship + replay).
-
-    Routing is host-affinity-aware (``_AffinityRouter``): jobs on a case
-    prefer the host that already built its MEP and holds warm jit/eval
-    caches, with cross-host work-stealing so no slot idles."""
-
-    name = "remote"
-    persistent = True
-    affinity = True
-
-    def __init__(self, hosts: List[Union[str, Dict[str, Any], FleetHost]],
-                 *, timeout_s: Optional[float] = None, retries: int = 1,
-                 server_timeout_s: float = 120.0,
-                 backoff_base_s: float = 0.05, backoff_max_s: float = 2.0,
-                 backoff_attempts: int = 4,
-                 quarantine_after: int = 3,
-                 probe_base_s: float = 0.5, probe_max_s: float = 5.0,
-                 chaos: Optional[FaultPlan] = None):
-        hosts = [h if isinstance(h, FleetHost) else FleetHost.from_dict(h)
-                 for h in hosts]
-        if not hosts:
-            raise ValueError("RemoteExecutor needs at least one FleetHost")
-        names = [h.name for h in hosts]
-        if len(set(names)) != len(names):
-            raise ValueError(f"duplicate fleet host names: {names}")
-        for h in hosts:
-            if h.transport not in ("spawn", "socket", "ssh"):
-                raise ValueError(
-                    f"fleet host {h.name}: unknown transport "
-                    f"{h.transport!r} (spawn|socket|ssh)")
-            if h.transport == "socket" and ":" not in h.address:
-                raise ValueError(f"fleet host {h.name}: socket transport "
-                                 f"needs address='host:port'")
-            if h.transport == "ssh" and not h.ssh:
-                raise ValueError(f"fleet host {h.name}: ssh transport "
-                                 f"needs ssh='user@host'")
-        super().__init__(sum(max(1, h.slots) for h in hosts),
-                         timeout_s=timeout_s, retries=retries, chaos=chaos)
-        self.hosts: Dict[str, FleetHost] = {h.name: h for h in hosts}
-        self.server_timeout_s = server_timeout_s
-        # reconnect/backoff knobs: a dead slot connection is
-        # re-established under a deterministic exponential schedule
-        # instead of staying dead until the next dispatch
-        self.backoff_base_s = backoff_base_s
-        self.backoff_max_s = backoff_max_s
-        self.backoff_attempts = max(0, backoff_attempts)
-        # health/quarantine knobs: quarantine_after consecutive faults
-        # sideline a host (while ≥1 healthy host remains); probes pace
-        # on their own backoff schedule until the host answers a ping
-        self.quarantine_after = max(1, quarantine_after)
-        self.probe_base_s = probe_base_s
-        self.probe_max_s = probe_max_s
-        self._servers: Dict[str, _ServerProc] = {}
-        self._server_lock = threading.Lock()
-        self._replicator = None       # lazy repro.core.replicate.Replicator
-        self._health_lock = threading.Lock()
-        self._consec_faults: Dict[str, int] = {}
-        self._quarantined: Dict[str, float] = {}   # host → next probe t
-        self._probe_idx: Dict[str, int] = {}       # host → probe attempt
-        self._rerouted: Dict[str, str] = {}        # case → origin host
-        self._ever_connected: set = set()          # slots once connected
-        self.reconnects = 0
-        self.quarantines = 0
-        self.readmissions = 0
-        self.reroutes = 0
-        # interpreter-exit backstop: spawned servers must die even when
-        # a crashed campaign never reaches close().  A weakref keeps
-        # atexit's registry from pinning the executor alive.
-        ref = weakref.ref(self)
-
-        def _cleanup(ref=ref):
-            ex = ref()
-            if ex is not None:
-                try:
-                    ex.close()
-                except Exception:  # noqa: BLE001 — interpreter teardown
-                    pass
-        atexit.register(_cleanup)
-
-    # -- health/fault telemetry ----------------------------------------
-    def fleet_events(self) -> Dict[str, int]:
-        """Lifetime fault-tolerance counters (journaled by the campaign
-        into its ``campaign_end`` record)."""
-        with self._health_lock:
-            return {"reconnects": self.reconnects,
-                    "quarantines": self.quarantines,
-                    "readmissions": self.readmissions,
-                    "reroutes": self.reroutes}
-
-    def _journal(self, ctx: Optional[WorkerContext], campaign_id: str,
-                 kind: str, **fields: Any) -> None:
-        if ctx is not None and ctx.db is not None:
-            try:
-                ctx.db.append(kind, campaign=campaign_id, **fields)
-            except OSError:
-                pass    # a full disk must not turn degradation into a hang
-
-    def _note_ok(self, slot: Tuple[str, int]) -> None:
-        with self._health_lock:
-            self._consec_faults[slot[0]] = 0
-
-    def _note_fault(self, slot, job, kind, router, ctx, campaign_id):
-        host = slot[0]
-        with self._health_lock:
-            self._consec_faults[host] = \
-                self._consec_faults.get(host, 0) + 1
-            n = self._consec_faults[host]
-            if host in self._quarantined or n < self.quarantine_after:
-                return
-            healthy = [h for h in self.hosts
-                       if h != host and h not in self._quarantined]
-            if not healthy:
-                return    # never quarantine the last healthy host
-            self._quarantined[host] = time.monotonic()
-            self._probe_idx[host] = 0
-            self.quarantines += 1
-        released = router.release_host(host)
-        with self._health_lock:
-            for c in set(released) | {job.case.name}:
-                self._rerouted[c] = host
-        self._journal(ctx, campaign_id, "host_quarantined", host=host,
-                      fault=kind, job=job.name, consecutive_faults=n,
-                      released_cases=sorted(released))
-
-    def _note_dispatch(self, slot, job, ctx, campaign_id):
-        case = job.case.name
-        with self._health_lock:
-            origin = self._rerouted.pop(case, None)
-            if origin is None or origin == slot[0]:
-                return
-            self.reroutes += 1
-        self._journal(ctx, campaign_id, "job_rerouted", job=job.name,
-                      case=case, origin=origin, host=slot[0])
-
-    def _probe_delay(self, attempt: int) -> float:
-        sched = backoff_schedule(self.probe_base_s, self.probe_max_s,
-                                 attempt + 1)
-        return sched[-1] if sched else self.probe_base_s
-
-    def _slot_gate(self, slot, router, ctx, campaign_id) -> bool:
-        host = slot[0]
-        if router.closed:
-            return True    # let get() drain and release the slot thread
-        with self._health_lock:
-            since = self._quarantined.get(host)
-            if since is None:
-                return True
-            attempt = self._probe_idx.get(host, 0)
-            due = since + self._probe_delay(attempt)
-            wait = due - time.monotonic()
-        if wait > 0:
-            time.sleep(min(wait, 0.1))
-            return False
-        # probe: re-establish this slot's connection and ping it.  For a
-        # spawn host this respawns the dead server (READY re-handshake
-        # in _server_port) — exactly the recovery a readmission needs.
-        try:
-            with self._slot_lock(slot):
-                w = self._ensure_worker(slot, ctx)
-                w.send({"ping": True})
-                w.recv(min(self.server_timeout_s, 30.0))
-        except (TimeoutError, EOFError, OSError, BrokenPipeError,
-                ValueError):
-            self._replace_worker(slot)
-            with self._health_lock:
-                if host in self._quarantined:
-                    self._probe_idx[host] = \
-                        self._probe_idx.get(host, 0) + 1
-                    self._quarantined[host] = time.monotonic()
-            return False
-        with self._health_lock:
-            if host not in self._quarantined:
-                return True    # another slot's probe already readmitted
-            del self._quarantined[host]
-            self._probe_idx.pop(host, None)
-            self._consec_faults[host] = 0
-            self.readmissions += 1
-        self._journal(ctx, campaign_id, "host_readmitted", host=host)
-        return True
-
-    # -- slots ---------------------------------------------------------
-    def _all_slots(self) -> List[Tuple[str, int]]:
-        """Host slots interleaved round-robin, so a job list shorter
-        than the fleet still spreads across hosts."""
-        cols = [[(h.name, i) for i in range(max(1, h.slots))]
-                for h in self.hosts.values()]
-        out: List[Tuple[str, int]] = []
-        depth = max(len(c) for c in cols)
-        for i in range(depth):
-            out.extend(c[i] for c in cols if i < len(c))
-        return out
-
-    def _slots_for(self, ctx: WorkerContext, n_jobs: int
-                   ) -> List[Tuple[str, int]]:
-        slots = self._all_slots()
-        return slots[:max(1, n_jobs)] if n_jobs < len(slots) else slots
-
-    def _slot_host(self, slot: Tuple[str, int]) -> str:
-        return slot[0]
-
-    # -- per-host spec rewriting ---------------------------------------
-    def _spec_for_slot(self, spec: Dict[str, Any],
-                       slot: Tuple[str, int]) -> Dict[str, Any]:
-        host = self.hosts[slot[0]]
-        if not (host.cache_path or host.patterns_path or host.db_path):
-            return spec            # shared filesystem: nothing to remap
-        spec = dict(spec)
-        if host.cache_path and spec.get("cache"):
-            spec["cache"] = dict(spec["cache"], path=host.cache_path)
-            if spec.get("lease_scope"):
-                # the derived lease keys on the cache path: keep the
-                # worker's re-derivation anchored to ITS journal file
-                spec["lease_scope"] = dict(spec["lease_scope"],
-                                           cache=host.cache_path)
-        if host.patterns_path and spec.get("patterns"):
-            spec["patterns"] = dict(spec["patterns"],
-                                    path=host.patterns_path)
-        if host.db_path and spec.get("db"):
-            spec["db"] = host.db_path
-        return spec
-
-    # -- journal replication -------------------------------------------
-    def _ensure_replicator(self, ctx: WorkerContext):
-        pairs: List[Tuple[str, str]] = []
-        for h in self.hosts.values():
-            if h.cache_path and ctx.cache is not None and ctx.cache.path:
-                pairs.append((ctx.cache.path, h.cache_path))
-            if h.patterns_path and ctx.patterns is not None \
-                    and ctx.patterns.path:
-                pairs.append((ctx.patterns.path, h.patterns_path))
-            if h.db_path and ctx.db is not None:
-                pairs.append((ctx.db.path, h.db_path))
-        if not pairs:
-            return None
-        with self._server_lock:
-            if self._replicator is None:
-                from repro.core.replicate import Replicator
-                self._replicator = Replicator()
-                self._replicator.start()
-            for a, b in pairs:
-                self._replicator.add(a, b)
-        return self._replicator
-
-    def run(self, jobs, ctx, *, campaign_id="", stop=None):
-        with self._health_lock:
-            self._rerouted.clear()
-        repl = self._ensure_replicator(ctx)
-        try:
-            return super().run(jobs, ctx, campaign_id=campaign_id,
-                               stop=stop)
-        finally:
-            if repl is not None:
-                # final drain: every append a host made during the
-                # campaign is home before the scheduler reads winners
-                repl.pump()
-                if ctx.cache is not None:
-                    ctx.cache.reload()
-                if ctx.patterns is not None and ctx.patterns.path:
-                    ctx.patterns.reload()
-
-    # -- transports ----------------------------------------------------
-    def _server_port(self, host: FleetHost) -> int:
-        with self._server_lock:
-            srv = self._servers.get(host.name)
-            if srv is None or not srv.alive():
-                if srv is not None:
-                    srv.kill()
-                srv = _ServerProc(host, timeout_s=self.server_timeout_s,
-                                  chaos=self.chaos)
-                self._servers[host.name] = srv
-            return srv.port
-
-    def _connect(self, slot: Tuple[str, int]):
-        host = self.hosts[slot[0]]
-        if host.transport == "ssh":
-            return _WorkerProc(_ssh_worker_cmd(host), dict(os.environ),
-                               slot)
-        if host.transport == "socket":
-            address = host.address
-        elif host.transport == "spawn":
-            address = f"127.0.0.1:{self._server_port(host)}"
-        else:
-            raise ValueError(f"fleet host {host.name}: unknown transport "
-                             f"{host.transport!r} (spawn|socket|ssh)")
-        return _SocketWorker(address, slot,
-                             connect_timeout_s=host.connect_timeout_s)
-
-    def _ensure_worker(self, slot: Tuple[str, int],
-                       ctx: Optional[WorkerContext]):
-        # connect OUTSIDE self._lock: a slow server start must not block
-        # other hosts' slots (the per-slot protocol lock in dispatch()
-        # already serializes re-entry for this slot)
-        with self._lock:
-            w = self._procs.get(slot)
-            if w is not None and w.alive():
-                return w
-        # reconnect with deterministic exponential backoff: a spawn
-        # server mid-restart (or a standing server bouncing) answers a
-        # later attempt, so one blip doesn't burn a whole job retry
-        delays = backoff_schedule(self.backoff_base_s, self.backoff_max_s,
-                                  self.backoff_attempts)
-        last: Optional[BaseException] = None
-        w = None
-        for i in range(len(delays) + 1):
-            try:
-                w = self._connect(slot)
-                break
-            except (EOFError, TimeoutError, OSError) as e:
-                last = e        # _ConnectError is an OSError subclass
-                if i < len(delays):
-                    time.sleep(delays[i])
-        if w is None:
-            raise _ConnectError(
-                f"slot {slot}: connect failed after "
-                f"{len(delays) + 1} attempts: {last}") from last
-        with self._health_lock:
-            if slot in self._ever_connected:
-                self.reconnects += 1
-            else:
-                self._ever_connected.add(slot)
-        with self._lock:
-            self._procs[slot] = w
-        return w
-
-    def warm(self, slots=None, timeout_s: float = 120.0) -> None:
-        super().warm(self._all_slots() if slots is None else slots,
-                     timeout_s)
-
-    def close(self) -> None:
-        with self._server_lock:
-            repl, self._replicator = self._replicator, None
-        if repl is not None:
-            repl.stop()           # stop() takes a final drain pump
-        super().close()           # closes slot connections / ssh pipes
-        with self._server_lock:
-            servers, self._servers = list(self._servers.values()), {}
-        for srv in servers:
-            srv.kill()
 
 
 def make_executor(kind: Optional[str], *, workers: Optional[int] = None,
-                  timeout_s: Optional[float] = None,
-                  hosts: Optional[List[Any]] = None) -> Executor:
+                  timeout_s: Optional[float] = None) -> Executor:
     """Executor factory behind the ``--executor=`` / ``executor=`` knobs
-    (None → REPRO_CAMPAIGN_EXECUTOR, default in-process).  ``remote``
-    takes its fleet from ``hosts`` (FleetHost / dict / name strings) or
-    the ``REPRO_FLEET_HOSTS`` env var (a JSON list of the same)."""
+    (None → REPRO_CAMPAIGN_EXECUTOR, default in-process)."""
     if kind is None:
         kind = os.environ.get("REPRO_CAMPAIGN_EXECUTOR", "inprocess")
     kind = kind.replace("_", "-")
@@ -1687,18 +898,8 @@ def make_executor(kind: Optional[str], *, workers: Optional[int] = None,
         return SubprocessExecutor(workers, timeout_s=timeout_s)
     if kind in ("local-cluster", "cluster"):
         return LocalClusterExecutor(workers, timeout_s=timeout_s)
-    if kind in ("remote", "fleet"):
-        if hosts is None:
-            env = os.environ.get("REPRO_FLEET_HOSTS", "")
-            if not env:
-                raise ValueError(
-                    "remote executor needs hosts=[...] or "
-                    "REPRO_FLEET_HOSTS (JSON list of FleetHost dicts "
-                    "or name strings)")
-            hosts = json.loads(env)
-        return RemoteExecutor(hosts, timeout_s=timeout_s)
     raise ValueError(f"unknown executor {kind!r}; choose from "
-                     f"inprocess, subprocess, local-cluster, remote")
+                     f"inprocess, subprocess, local-cluster")
 
 
 # ---------------------------------------------------------------------------
@@ -1723,12 +924,11 @@ def _apply_inject(inject: Dict[str, Any]) -> None:
 
 
 class _SpecServer:
-    """The worker-side spec interpreter, shared by every transport: one
-    instance per worker *process*, handling eval specs one
-    ``handle(spec) → reply`` call at a time (or concurrently, from the
-    remote server's connection threads).  Platform/cache/store/db
-    handles are memoized per spec coordinates so a long-lived process
-    serving many jobs keeps its warm jit/eval caches."""
+    """The worker-side spec interpreter: one instance per worker
+    *process*, handling eval specs one ``handle(spec) → reply`` call at
+    a time.  Platform/cache/store/db handles are memoized per spec
+    coordinates so a long-lived process serving many jobs keeps its
+    warm jit/eval caches."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -1736,20 +936,6 @@ class _SpecServer:
         self._caches: Dict[Tuple, EvalCache] = {}
         self._stores: Dict[Tuple, PatternStore] = {}
         self._dbs: Dict[str, ResultsDB] = {}
-        # scripted fault injection (repro.core.chaos): None outside the
-        # chaos harness — REPRO_CHAOS reaches spawned workers via the
-        # executor env stamp, a standing server via its own environment
-        self._chaos = ChaosInjector.from_env()
-
-    def handle_with_faults(self, spec: Dict[str, Any]
-                           ) -> Tuple[Dict[str, Any], List[Any]]:
-        """``(reply, drop_faults)``: fire any scripted faults due for
-        this spec (kill/stall/poison happen here, in place), then handle
-        it.  The returned ``drop_connection`` faults are for the
-        transport to honor at reply time — only the TCP server can tear
-        a line mid-send; stdio callers use ``handle`` and ignore them."""
-        drops = self._chaos.fire(spec) if self._chaos is not None else []
-        return self.handle(spec), drops
 
     def handle(self, spec: Dict[str, Any]) -> Dict[str, Any]:
         try:
@@ -1806,9 +992,7 @@ class _SpecServer:
 
 def worker_main() -> int:
     """Line-JSON worker loop over stdio: read an eval spec, run the §3.2
-    search for its job, write the full OptResult back (the socket
-    transport runs the same ``_SpecServer`` behind
-    ``scripts/remote_worker.py``)."""
+    search for its job, write the full OptResult back."""
     # The pipe to the scheduler is fd 1 at startup.  Everything else the
     # worker (or jax) prints must go to stderr, so dup the protocol fd
     # away and point stdout at stderr.
@@ -1827,9 +1011,7 @@ def worker_main() -> int:
             reply: Dict[str, Any] = {"ok": False, "type": "ProtocolError",
                                      "error": f"{e}"[:1000]}
         else:
-            # drop_connection faults are TCP-only; over pipes they are
-            # collected and ignored (the pipe can't tear a line cleanly)
-            reply, _ = server.handle_with_faults(spec)
+            reply = server.handle(spec)
         proto.write(json.dumps(json_safe(reply), default=str) + "\n")
         proto.flush()
     return 0

@@ -42,10 +42,6 @@ greedy decode continuously:
   trace shows on the device's clock what the host did in each idle gap.
   They record only while a profiler runs and cost under a microsecond
   each otherwise.
-
-``FixedBatchServer`` preserves the pre-continuous baseline (single shared
-decode position, one prefill call per request, prompts padded to one
-``prompt_len``) for the table-9 old-vs-new serving benchmark.
 """
 from __future__ import annotations
 
@@ -470,112 +466,3 @@ class BatchedServer:
             self.step()
         return self.finished
 
-
-class FixedBatchServer:
-    """Pre-continuous baseline: single shared decode position (all slots
-    must stay length-aligned; prompts are padded to one ``prompt_len``),
-    one prefill call per admitted request, fresh jit trace per shape.
-    Kept verbatim for the table-9 old-vs-new serving benchmark."""
-
-    def __init__(self, model, params, *, slots: int = 4, prompt_len: int = 32,
-                 max_len: int = 128, eos_id: Optional[int] = None,
-                 telemetry_site: str = "attention",
-                 telemetry: Optional[ops.Telemetry] = None):
-        assert model.cfg.family != "encdec", "use generate() for enc-dec"
-        self.model = model
-        self.params = params
-        self.slots = slots
-        self.prompt_len = prompt_len
-        self.max_len = max_len
-        self.eos_id = eos_id
-        self.site = telemetry_site
-        self.telemetry = telemetry if telemetry is not None else ops.telemetry
-        self.queue: List[Request] = []
-        self.active: List[Optional[Request]] = [None] * slots
-        self.finished: List[Request] = []
-        self.pos = np.zeros(slots, np.int32)
-        self.cache = model.init_cache(slots, max_len)
-        self.swap_epochs = 0
-        self._rid = itertools.count()
-        self._epoch = ops.registry_epoch()
-        self._trace_steps()
-
-    def _trace_steps(self) -> None:
-        self._step = jax.jit(self.model.decode_step)
-        self._prefill_one = jax.jit(
-            lambda p, t: self.model.prefill(p, t, max_len=self.max_len))
-
-    def _refresh_impls(self) -> None:
-        epoch = ops.registry_epoch()
-        if epoch != self._epoch:
-            self._epoch = epoch
-            self.swap_epochs += 1
-            self._trace_steps()
-
-    def submit(self, prompt: np.ndarray, max_new: int = 16) -> Request:
-        req = Request(rid=next(self._rid), prompt=prompt, max_new=max_new)
-        self.queue.append(req)
-        return req
-
-    def _finish(self, req: Request, slot: Optional[int]) -> None:
-        req.done = True
-        self.finished.append(req)
-        if slot is not None:
-            self.active[slot] = None
-
-    def _admit(self):
-        for s in range(self.slots):
-            if self.active[s] is None and self.queue:
-                req = self.queue.pop(0)       # FIFO drain order
-                logits, cache1 = self._prefill_one(
-                    self.params, jnp.asarray(req.prompt[None, :]))
-
-                def put(big, one):
-                    return big.at[:, s:s + 1].set(one.astype(big.dtype))
-                self.cache = jax.tree.map(put, self.cache, cache1)
-                tok = int(jnp.argmax(
-                    logits[0, -1, :self.model.cfg.vocab_size]))
-                req.tokens.append(tok)
-                self.telemetry.observe(self.site, scale=len(req.prompt),
-                                       tokens=len(req.prompt),
-                                       kind="prefill")
-                if ((self.eos_id is not None and tok == self.eos_id)
-                        or len(req.tokens) >= req.max_new):
-                    self._finish(req, None)
-                    continue
-                self.active[s] = req
-                self.pos[s] = len(req.prompt)
-
-    def step(self):
-        """One decode step for all occupied slots (single shared pos)."""
-        self._refresh_impls()
-        self._admit()
-        live = [s for s in range(self.slots) if self.active[s] is not None]
-        if not live:
-            return False
-        toks = np.zeros((self.slots, 1), np.int32)
-        for s in live:
-            toks[s, 0] = self.active[s].tokens[-1]
-        pos = int(self.pos[live[0]] + len(self.active[live[0]].tokens) - 1)
-        logits, self.cache = self._step(self.params, self.cache,
-                                        jnp.asarray(toks), jnp.int32(pos))
-        nxt = np.asarray(jnp.argmax(
-            logits[:, -1, :self.model.cfg.vocab_size], axis=-1))
-        for s in live:
-            req = self.active[s]
-            tok = int(nxt[s])
-            req.tokens.append(tok)
-            self.telemetry.observe(
-                self.site, scale=int(self.pos[s]) + len(req.tokens) - 1,
-                tokens=1, kind="decode")
-            if ((self.eos_id is not None and tok == self.eos_id)
-                    or len(req.tokens) >= req.max_new):
-                self._finish(req, s)
-        return True
-
-    def run(self, max_steps: int = 1000) -> List[Request]:
-        for _ in range(max_steps):
-            if not self.queue and all(a is None for a in self.active):
-                break
-            self.step()
-        return self.finished

@@ -91,13 +91,6 @@ def make_server(**kw):
     return BatchedServer(model, params, **kw)
 
 
-def make_fixed_server(**kw):
-    from repro.serve import FixedBatchServer
-    model = StubModel()
-    params = model.init_params(jax.random.PRNGKey(0))
-    return FixedBatchServer(model, params, **kw)
-
-
 def stub_generate(prompt, max_new, eos_id=None):
     """Fixed-batch greedy reference for a single prompt, via generate()."""
     from repro.serve import generate

@@ -9,7 +9,6 @@ from repro.core import (Campaign, CaseJob, CPUPlatform, EvalCache,
                         EvalRecord, HeuristicProposer, MEPConstraints,
                         OptConfig, PatternStore, ResultsDB,
                         TPUModelPlatform, canonical_spec, get_case, optimize)
-from repro.core.evalcache import this_host
 from repro.core.kernelcase import ArraySpec, KernelCase
 from repro.core.proposer import Proposer
 
@@ -287,7 +286,7 @@ def test_measured_platform_fans_out_under_timing_lease(tmp_path):
     assert Campaign(CPUPlatform(), max_workers=3).max_workers == 3
     cache = EvalCache(str(tmp_path / "ec.jsonl"))
     assert Campaign(CPUPlatform(), cache=cache).lease_path \
-        == cache.path + ".timelease@" + this_host()
+        == cache.path + ".timelease"
     assert Campaign(CPUPlatform()).lease_path            # tempdir fallback
     assert Campaign(TPUModelPlatform()).lease_path is None
 

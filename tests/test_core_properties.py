@@ -210,7 +210,7 @@ def test_adaptive_stopping_preserves_fixed_r_winner(n_cands, noise, seed):
     r_cap, k = 30, 3
     means = [1.0 * (1.3 ** i) for i in range(n_cands)]   # ≥30% separation
     random.Random(seed).shuffle(means)
-    streams = [_noise_samples(m, noise, (seed, i), r_cap)
+    streams = [_noise_samples(m, noise, f"{seed}:{i}", r_cap)
                for i, m in enumerate(means)]
 
     fixed = [trimmed_mean(s, k) for s in streams]

@@ -20,7 +20,6 @@ from repro.core import (Campaign, CaseJob, CPUPlatform, EvalCache,
                         MEPConstraints, OptConfig, Platform,
                         TPUModelPlatform, build_mep, get_case, wallclock)
 from repro.core import measure as measure_mod
-from repro.core.evalcache import this_host
 from repro.core.measure import (TimingLease, effective_k, measure_callable,
                                 resolve_lease, trimmed_stats)
 from repro.core.workers import run_case_job
@@ -352,7 +351,7 @@ def test_measured_campaign_fans_out_across_processes(tmp_path):
         slots = {s for _, s in ex.dispatch_log}
         ex.close()
     assert len(slots) == 2                   # both workers actually used
-    assert camp.lease_path == cache.path + ".timelease@" + this_host()
+    assert camp.lease_path == cache.path + ".timelease"
     for res in results:
         assert isinstance(res, OptResult)
         assert res.timing_reps > 0

@@ -13,7 +13,8 @@ import sys
 
 import pytest
 
-from repro.core import PatternStore, get_case
+from repro.core import (EvalCache, EvalRecord, PatternStore,
+                        canonical_spec, get_case)
 
 HELPER = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                       "_patterns_proc.py")
@@ -25,6 +26,11 @@ def _case():
 
 def _base():
     return dict(_case().baseline_variant)
+
+
+def _lines(path):
+    with open(path) as f:
+        return [line for line in f if line.strip()]
 
 
 # ------------------------------------------------------- merge + replay ---
@@ -103,9 +109,32 @@ def test_compaction_bounds_journal_and_preserves_state(tmp_path):
     assert len(s2) == 1 and s2.patterns[0].gain == pytest.approx(6.4)
 
 
-def test_reader_survives_concurrent_compaction(tmp_path):
+@pytest.mark.parametrize("kind", ["PatternStore", "EvalCache"])
+def test_reader_survives_concurrent_compaction(kind, tmp_path):
     """A store whose file is compacted (inode swap) under it rebuilds
-    its merged view from the new journal on the next read."""
+    its view from the new journal on the next read."""
+    if kind == "EvalCache":
+        path = str(tmp_path / "cache.jsonl")
+        reader, writer = EvalCache(path), EvalCache(path)
+
+        def spec(i):
+            return canonical_spec("gemm", {"t": i}, 1, "cpu")
+
+        writer.get_or_compute(spec(0), lambda: EvalRecord(time_s=1.0))
+        reader.reload()
+        assert reader.lookup(spec(0)).time_s == 1.0     # caught up
+        writer.COMPACT_MIN_LINES = 4
+        for n in range(20):       # churn one key: the writer compacts
+            writer.get_or_compute(
+                spec(1), lambda n=n: EvalRecord(time_s=2.0 + n),
+                accept=lambda r: False)
+        writer.get_or_compute(spec(2), lambda: EvalRecord(time_s=9.0))
+        assert len(_lines(path)) < 22                   # it did compact
+        reader.reload()
+        assert reader.lookup(spec(1)).time_s == 21.0    # last wins
+        assert reader.lookup(spec(2)).time_s == 9.0
+        assert len(reader) == len(writer) == 3
+        return
     path = str(tmp_path / "pat.jsonl")
     reader, writer = PatternStore(path), PatternStore(path)
     base = _base()
@@ -166,11 +195,7 @@ def test_legacy_array_store_migrates_to_journal(tmp_path):
     assert len(s) == 1 and s.patterns[0].gain == 2.5
     with open(path) as f:                    # rewritten as JSONL
         lines = [json.loads(line) for line in f if line.strip()]
-    # the rewrite closes with a compaction-epoch marker (replication
-    # coordination, repro.core.replicate) — patterns are the rest
-    pats = [ln for ln in lines if ln.get("ev") != "compact"]
-    assert len(pats) == 1 and pats[0]["delta"] == {"block_m": 128}
-    assert lines[-1].get("ev") == "compact"
+    assert len(lines) == 1 and lines[0]["delta"] == {"block_m": 128}
 
 
 # ------------------------------------------------ multi-process hammer ----

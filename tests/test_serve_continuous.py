@@ -14,8 +14,7 @@ import pytest
 
 from repro.core import get_case
 from repro.kernels import ops
-from serving_stub import (StubModel, make_server, make_fixed_server,
-                          prompts, stub_generate)
+from serving_stub import StubModel, make_server, stub_generate
 
 
 @pytest.fixture(autouse=True)
@@ -206,12 +205,3 @@ def test_recurrent_real_model_ragged_equivalence():
         assert r.tokens == [int(t) for t in ref[:len(r.tokens)]], \
             f"rid {r.rid} diverged"
 
-
-def test_fixed_batch_server_baseline_still_serves():
-    """The retained baseline pads everything to one prompt_len — used by
-    the table-9 benchmark as the 'before' engine."""
-    srv = make_fixed_server(slots=2, max_len=64, prompt_len=8)
-    reqs = [srv.submit(p, max_new=4) for p in prompts(5)]
-    fin = srv.run()
-    assert all(r.done and len(r.tokens) == 4 for r in reqs)
-    assert [r.rid for r in fin] == [0, 1, 2, 3, 4]
